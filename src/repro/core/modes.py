@@ -31,23 +31,13 @@ class MemoryMode(enum.Enum):
     DEVMEM = "DevMem"
 
 
-def _has_host_memory_kind() -> bool:
-    try:
-        dev = jax.devices()[0]
-        kinds = [m.kind for m in dev.addressable_memories()]
-        return "pinned_host" in kinds
-    except Exception:
-        return False
-
-
 def host_placement(x):
     """Place an array in host memory.
 
     We keep host-resident data as NUMPY arrays: genuinely host RAM on
     every backend, and it sidesteps jax's sticky <host> memory-space
     avals on sliced pinned_host buffers (device_put of a numpy array is
-    the portable H2D DMA). On TPU deployments the ``pinned_host``
-    memory-kind variant applies — see _has_host_memory_kind.
+    the portable H2D DMA).
     """
     import numpy as np
     return np.asarray(jax.device_get(x))

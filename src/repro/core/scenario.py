@@ -832,7 +832,9 @@ def _pool_executor(workers: int):
     inline path.  Prefers the fork start method (workers inherit the
     imported module graph; the at-fork hooks above give each child
     empty caches and an empty scratch pool) and falls back to the
-    platform default where fork is unavailable."""
+    platform default where fork is unavailable.  Run ``workers > 1``
+    only in a process that has not touched the TPU: a forked child
+    shares the parent's runtime threads and cannot use the chip."""
     if workers <= 1:
         return None
     import concurrent.futures

@@ -1,6 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels: padding to block
 multiples, block-size selection via the paper's overlap bound
-(core.overlap), GQA head folding, and interpret-mode fallback on CPU.
+(core.overlap) and GQA head folding.  Every wrapper compiles the kernel
+for the TPU unless the caller passes ``interpret=True`` (the Pallas
+interpreter, which is how the CPU tests run them).
 """
 from __future__ import annotations
 
@@ -15,12 +17,6 @@ from repro.kernels.paged_attention import paged_attention_raw
 from repro.kernels.streaming_gemm import streaming_gemm_raw
 
 
-def _auto_interpret(interpret):
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
-
-
 def _round_up(x, m):
     return (x + m - 1) // m * m
 
@@ -28,13 +24,12 @@ def _round_up(x, m):
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def streaming_gemm(a, b, bm: int | None = None, bn: int | None = None,
                    bk: int | None = None,
-                   interpret: bool | None = None):
+                   interpret: bool = False):
     """Paged streaming GEMM with automatic padding to block multiples.
 
     Block sizes default to the unified page-aligned overlap-bound
     chooser (``core.overlap.choose_gemm_blocks``); pass explicit
     bm/bn/bk to override."""
-    interpret = _auto_interpret(interpret)
     M, K = a.shape
     _, N = b.shape
     if bm is None or bn is None or bk is None:
@@ -53,9 +48,8 @@ def streaming_gemm(a, b, bm: int | None = None, bn: int | None = None,
 @functools.partial(jax.jit,
                    static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention(q, k, v, causal: bool = True, bq: int = 256,
-                    bk: int = 512, interpret: bool | None = None):
+                    bk: int = 512, interpret: bool = False):
     """q: (B, Tq, H, D); k, v: (B, Tk, KH, D) — GQA folded internally."""
-    interpret = _auto_interpret(interpret)
     B, Tq, H, D = q.shape
     _, Tk, KH, _ = k.shape
     G = H // KH
@@ -83,6 +77,6 @@ def flash_attention(q, k, v, causal: bool = True, bq: int = 256,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q, k_pages, v_pages, table, lens,
-                    interpret: bool | None = None):
+                    interpret: bool = False):
     return paged_attention_raw(q, k_pages, v_pages, table, lens,
-                               interpret=_auto_interpret(interpret))
+                               interpret=interpret)

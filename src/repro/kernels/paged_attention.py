@@ -33,7 +33,12 @@ NEG_INF = -1e30
 
 def _paged_kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
                   acc_ref, m_ref, l_ref, *, page: int, max_pages: int,
-                  scale: float, n_kv: int):
+                  scale: float, n_kv: int, head_dim: int):
+    """One (sequence, page) grid step.  q/o blocks are (1, KH, G, D);
+    K/V blocks are (1, page, KH*D) with KV head h in lanes
+    [h*D, (h+1)*D).  Scratch is per KV head: acc (KH, G, D), running max
+    and denominator (KH, G, 1) — every value stays 2-D per head, so
+    Mosaic never has to re-lay a vector out across a reshape."""
     b, pi = pl.program_id(0), pl.program_id(1)
 
     @pl.when(pi == 0)
@@ -47,35 +52,31 @@ def _paged_kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(pi < n_pages_used)
     def _step():
-        q = q_ref[0].astype(jnp.float32)                 # (H, D)
-        k = k_ref[0].astype(jnp.float32)                 # (page, KH, D)
-        v = v_ref[0]                                     # (page, KH, D)
-        H, D = q.shape
-        G = H // n_kv
-        qg = q.reshape(n_kv, G, D)
-        s = jnp.einsum("hgd,phd->hgp", qg, k,
-                       preferred_element_type=jnp.float32) * scale
-        pos = pi * page + jax.lax.broadcasted_iota(
-            jnp.int32, (n_kv, G, page), 2)
-        s = jnp.where(pos < seq_len, s, NEG_INF)
-        m_prev = m_ref[...].reshape(n_kv, G)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[...].reshape(n_kv, G) * corr + p.sum(axis=-1)
-        upd = jnp.einsum("hgp,phd->hgd", p.astype(jnp.float32),
-                         v.astype(jnp.float32))
-        acc = acc_ref[...].reshape(n_kv, G, D)
-        acc_ref[...] = (acc * corr[..., None] + upd).reshape(H, D)
-        m_ref[...] = m_new.reshape(H)
-        l_ref[...] = l_new.reshape(H)
+        for h in range(n_kv):
+            lanes = slice(h * head_dim, (h + 1) * head_dim)
+            q = q_ref[0, h].astype(jnp.float32)              # (G, D)
+            k = k_ref[0, :, lanes].astype(jnp.float32)       # (page, D)
+            v = v_ref[0, :, lanes].astype(jnp.float32)       # (page, D)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (G, page)
+            pos = pi * page + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(pos < seq_len, s, NEG_INF)
+            m_prev = m_ref[h]                                # (G, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + p.sum(axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(pi == max_pages - 1)
     def _flush():
-        H, D = q_ref[0].shape
-        o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], 1e-30)[:, None]
-                    ).astype(o_ref.dtype)
+        for h in range(n_kv):
+            o_ref[0, h] = (acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)
+                           ).astype(o_ref.dtype)
 
 
 def paged_attention_raw(q, k_pages, v_pages, table, lens, *,
@@ -83,30 +84,40 @@ def paged_attention_raw(q, k_pages, v_pages, table, lens, *,
     B, H, D = q.shape
     P, page, KH, _ = k_pages.shape
     _, max_pages = table.shape
+    G = H // KH
     scale = 1.0 / math.sqrt(D)
+    # GQA folding happens here, outside the kernel: both reshapes only
+    # regroup trailing dims, so they are free (no HBM copy of the pool)
+    qg = q.reshape(B, KH, G, D)
+    kf = k_pages.reshape(P, page, KH * D)
+    vf = v_pages.reshape(P, page, KH * D)
     kernel = functools.partial(_paged_kernel, page=page,
-                               max_pages=max_pages, scale=scale, n_kv=KH)
+                               max_pages=max_pages, scale=scale, n_kv=KH,
+                               head_dim=D)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,           # (table, lens) land in SMEM
         grid=(B, max_pages),
         in_specs=[
-            pl.BlockSpec((1, H, D), lambda b, pi, table, lens: (b, 0, 0)),
+            pl.BlockSpec((1, KH, G, D),
+                         lambda b, pi, table, lens: (b, 0, 0, 0)),
             # the SMMU moment: translate page id -> pool slot in index_map
-            pl.BlockSpec((1, page, KH, D),
-                         lambda b, pi, table, lens: (table[b, pi], 0, 0, 0)),
-            pl.BlockSpec((1, page, KH, D),
-                         lambda b, pi, table, lens: (table[b, pi], 0, 0, 0)),
+            pl.BlockSpec((1, page, KH * D),
+                         lambda b, pi, table, lens: (table[b, pi], 0, 0)),
+            pl.BlockSpec((1, page, KH * D),
+                         lambda b, pi, table, lens: (table[b, pi], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, pi, table, lens: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, KH, G, D),
+                               lambda b, pi, table, lens: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((H, D), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
+            pltpu.VMEM((KH, G, D), jnp.float32),
+            pltpu.VMEM((KH, G, 1), jnp.float32),
+            pltpu.VMEM((KH, G, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
         interpret=interpret,
-    )(table, lens, q, k_pages, v_pages)
+    )(table, lens, qg, kf, vf)
+    return out.reshape(B, H, D)

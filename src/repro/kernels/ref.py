@@ -29,6 +29,22 @@ def flash_ref(q, k, v, causal=True):
                       ).astype(q.dtype)
 
 
+def gqa_flash_ref(q, k, v, causal=True):
+    """q: (B, Tq, H, D); k, v: (B, Tk, KH, D) — the layout of
+    ``ops.flash_attention``: q head kh*G + g reads KV head kh."""
+    B, Tq, H, D = q.shape
+    Tk, KH = k.shape[1], k.shape[2]
+    qg = q.reshape(B, Tq, KH, H // KH, D).astype(jnp.float32)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(jnp.float32)
+                   ) / math.sqrt(D)
+    if causal:
+        mask = jnp.arange(Tq)[:, None] >= jnp.arange(Tk)[None, :]
+        s = jnp.where(mask, s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
+    return out.reshape(B, Tq, H, D).astype(q.dtype)
+
+
 def paged_ref(q, k_pages, v_pages, table, lens):
     """Gather pages into contiguous caches, then masked attention."""
     B, H, D = q.shape

@@ -11,6 +11,7 @@ import argparse
 
 from repro.configs import get_config, get_reduced
 from repro.configs.base import RunConfig, ShapeConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.runtime.train_loop import Trainer, TrainerConfig
 
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=3e-3)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if args.mesh == "host":
         mesh = make_host_mesh(1, 1)

@@ -56,6 +56,18 @@ def test_flash_matches_ref(tq, tk, h, kh, d, causal):
     np.testing.assert_allclose(out, want, rtol=3e-5, atol=3e-5)
 
 
+@pytest.mark.parametrize("tq,h,kh,d", [(64, 14, 2, 64), (32, 4, 4, 32)])
+def test_flash_matches_gqa_ref(tq, h, kh, d):
+    """The (B, T, H, D) GQA reference agrees with the kernel wrapper."""
+    q = jax.random.normal(KEY, (1, tq, h, d), jnp.float32)
+    k = jax.random.normal(jax.random.PRNGKey(1), (1, tq, kh, d), jnp.float32)
+    v = jax.random.normal(jax.random.PRNGKey(2), (1, tq, kh, d), jnp.float32)
+    out = flash_attention(q, k, v, causal=True, bq=32, bk=32,
+                          interpret=True)
+    np.testing.assert_allclose(out, ref.gqa_flash_ref(q, k, v),
+                               rtol=3e-5, atol=3e-5)
+
+
 @pytest.mark.parametrize("b,h,kh,d,page,mp", [(3, 8, 2, 32, 16, 4),
                                               (2, 4, 4, 64, 8, 6),
                                               (1, 16, 1, 16, 32, 2)])
