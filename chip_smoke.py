@@ -44,6 +44,7 @@ from repro.models.model import Model  # noqa: E402
 from repro.runtime.train_loop import Trainer, TrainerConfig  # noqa: E402
 
 ARCH = "qwen2-0.5b"
+SEED = 0
 SLOTS, MAX_SEQ, REQUESTS, NEW_TOKENS = 8, 2048, 16, 32
 # Tolerances for bf16 (8-bit mantissa, one rounding = 2^-8 ~ 3.9e-3):
 # - chip vs host prefill logits, relative L2 error: the two backends
@@ -53,8 +54,11 @@ LOGITS_TOL = 5e-2
 #   accumulation in both, so a few bf16 roundings of the output apart;
 KERNEL_TOL = 2e-2
 # - 2x2 mesh vs one chip, per-step |loss difference| over the loss:
-#   the same f32 loss with sharded reductions summed in another order.
-LOSS_TOL = 1e-2
+#   the same f32 loss with sharded reductions summed in another order
+#   reads 1.6e-5 on four v5e chips and 1.8e-5 at reduced size on the
+#   CPU; dropping half of the sharded run's batch reads 2.1e-2 on the
+#   chips and 2.1e-3 on the CPU.
+LOSS_TOL = 2e-4
 # --chips 4 training shape: batch 8 splits over data=2, and with full
 # remat the one-chip run needs about 9 GB of the chip's 16 GB.
 TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS = 8, 512, 3
@@ -256,7 +260,6 @@ def phase_sharded_training(cfg: ModelConfig, seed: int):
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
-    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
     dev = require_tpu()
@@ -270,12 +273,11 @@ def main() -> None:
     meter = CompileMeter()
     cfg = get_config(ARCH)
     if args.chips == 4:
-        meter.phase("sharded-training", phase_sharded_training, cfg,
-                    args.seed)
+        meter.phase("sharded-training", phase_sharded_training, cfg, SEED)
     else:
-        params = meter.phase("serving", phase_serving, cfg, args.seed)
-        meter.phase("logits", phase_logits, cfg, params, args.seed)
-        meter.phase("kernels", phase_kernels, cfg, args.seed)
+        params = meter.phase("serving", phase_serving, cfg, SEED)
+        meter.phase("logits", phase_logits, cfg, params, SEED)
+        meter.phase("kernels", phase_kernels, cfg, SEED)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}))

@@ -31,18 +31,16 @@ def flash_ref(q, k, v, causal=True):
 
 def gqa_flash_ref(q, k, v, causal=True):
     """q: (B, Tq, H, D); k, v: (B, Tk, KH, D) — the layout of
-    ``ops.flash_attention``: q head kh*G + g reads KV head kh."""
+    ``ops.flash_attention``: q head kh*G + g reads KV head kh.  Folds the
+    heads into ``flash_ref``'s batch axis."""
     B, Tq, H, D = q.shape
     Tk, KH = k.shape[1], k.shape[2]
-    qg = q.reshape(B, Tq, KH, H // KH, D).astype(jnp.float32)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(jnp.float32)
-                   ) / math.sqrt(D)
-    if causal:
-        mask = jnp.arange(Tq)[:, None] >= jnp.arange(Tk)[None, :]
-        s = jnp.where(mask, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
-    return out.reshape(B, Tq, H, D).astype(q.dtype)
+    G = H // KH
+    qf = q.reshape(B, Tq, KH, G, D).transpose(0, 2, 3, 1, 4).reshape(-1, Tq, D)
+    kf = jnp.repeat(k.transpose(0, 2, 1, 3).reshape(-1, Tk, D), G, axis=0)
+    vf = jnp.repeat(v.transpose(0, 2, 1, 3).reshape(-1, Tk, D), G, axis=0)
+    return flash_ref(qf, kf, vf, causal).reshape(B, KH, G, Tq, D) \
+        .transpose(0, 3, 1, 2, 4).reshape(B, Tq, H, D)
 
 
 def paged_ref(q, k_pages, v_pages, table, lens):

@@ -3,8 +3,10 @@
 A cache only hits when a later run looks in the same directory, so the
 directory is fixed: ``$JAX_COMPILATION_CACHE_DIR`` where it is set,
 otherwise ``.jax_cache`` at the root of this checkout (gitignored).
-Entry points call ``enable_compile_cache()`` once at start-up; importing
-this module changes nothing.
+Every program is cached, however fast it compiled: JAX's default skips
+those under one second, so the Pallas kernels would recompile on every
+run.  Entry points call ``enable_compile_cache()`` once at start-up;
+importing this module changes nothing.
 """
 from __future__ import annotations
 
@@ -22,4 +24,5 @@ def enable_compile_cache() -> str:
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
         str(CHECKOUT_CACHE_DIR)
     jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     return path

@@ -24,6 +24,10 @@ def serve(cfg: ModelConfig, *, slots: int, max_seq: int, requests: int,
           new_tokens: int, seed: int):
     """Serve ``requests`` synthetic requests with seeded random weights
     until the queue drains.  Returns ``(engine, requests, stats)``."""
+    if max_seq < max(PROMPT_LENS) + new_tokens:
+        raise ValueError(
+            f"max_seq {max_seq} cannot hold a {max(PROMPT_LENS)}-token "
+            f"prompt and {new_tokens} new tokens")
     params = Model(cfg, remat="none").init(jax.random.PRNGKey(seed))
     eng = ServingEngine(cfg, params, slots=slots, max_seq=max_seq)
     rng = np.random.default_rng(seed)

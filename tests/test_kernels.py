@@ -37,7 +37,9 @@ def test_gemm_int8_exact():
 
 @pytest.mark.parametrize("tq,tk,h,kh,d", [(128, 128, 4, 2, 32),
                                           (64, 256, 8, 8, 64),
-                                          (96, 96, 6, 1, 16)])
+                                          (96, 96, 6, 1, 16),
+                                          (64, 64, 14, 2, 64),
+                                          (32, 32, 4, 4, 32)])
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_matches_ref(tq, tk, h, kh, d, causal):
     if not causal and tq != tk:
@@ -47,24 +49,7 @@ def test_flash_matches_ref(tq, tk, h, kh, d, causal):
     v = jax.random.normal(jax.random.PRNGKey(2), (2, tk, kh, d), jnp.float32)
     out = flash_attention(q, k, v, causal=causal, bq=32, bk=32,
                           interpret=True)
-    g = h // kh
-    qf = q.reshape(2, tq, kh, g, d).transpose(0, 2, 3, 1, 4).reshape(-1, tq, d)
-    kf = jnp.repeat(k.transpose(0, 2, 1, 3).reshape(-1, tk, d), g, axis=0)
-    vf = jnp.repeat(v.transpose(0, 2, 1, 3).reshape(-1, tk, d), g, axis=0)
-    want = ref.flash_ref(qf, kf, vf, causal).reshape(2, kh, g, tq, d) \
-        .transpose(0, 3, 1, 2, 4).reshape(2, tq, h, d)
-    np.testing.assert_allclose(out, want, rtol=3e-5, atol=3e-5)
-
-
-@pytest.mark.parametrize("tq,h,kh,d", [(64, 14, 2, 64), (32, 4, 4, 32)])
-def test_flash_matches_gqa_ref(tq, h, kh, d):
-    """The (B, T, H, D) GQA reference agrees with the kernel wrapper."""
-    q = jax.random.normal(KEY, (1, tq, h, d), jnp.float32)
-    k = jax.random.normal(jax.random.PRNGKey(1), (1, tq, kh, d), jnp.float32)
-    v = jax.random.normal(jax.random.PRNGKey(2), (1, tq, kh, d), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, bq=32, bk=32,
-                          interpret=True)
-    np.testing.assert_allclose(out, ref.gqa_flash_ref(q, k, v),
+    np.testing.assert_allclose(out, ref.gqa_flash_ref(q, k, v, causal),
                                rtol=3e-5, atol=3e-5)
 
 
