@@ -199,6 +199,7 @@ def apply_act(x, cfg: ModelConfig):
     return jax.nn.silu(x) if cfg.act == "silu" else jax.nn.gelu(x)
 
 
+@jax.named_scope("mlp")
 def apply_mlp(p, x, cfg: ModelConfig):
     if cfg.glu:
         h = apply_act(x @ p["wi_gate"], cfg) * (x @ p["wi_up"])
@@ -232,6 +233,7 @@ def qkv_proj(p, x, cfg: ModelConfig):
     return q, k, v
 
 
+@jax.named_scope("attention")
 def attention_train(p, x, cfg: ModelConfig, positions, causal=True,
                     kv=None):
     """Full-sequence attention (train / prefill). kv: optional external
@@ -246,6 +248,7 @@ def attention_train(p, x, cfg: ModelConfig, positions, causal=True,
     return jnp.einsum("bthk,hkd->btd", out, p["wo"]), (k, v)
 
 
+@jax.named_scope("attention")
 def attention_decode(p, x, cfg: ModelConfig, cache):
     """x: (B, d) one token. cache: {"k","v": (B,S,KH,hd), "len": (B,)}.
 
@@ -268,17 +271,21 @@ def attention_decode(p, x, cfg: ModelConfig, cache):
         vs = jnp.max(jnp.abs(v), -1) / 127.0 + 1e-8
         kq = jnp.round(k / ks[..., None]).astype(jnp.int8)
         vq = jnp.round(v / vs[..., None]).astype(jnp.int8)
-        kc = cache["k"].at[bidx, pos].set(kq)
-        vc = cache["v"].at[bidx, pos].set(vq)
-        ksc = cache["k_scale"].at[bidx, pos].set(ks.astype(jnp.float16))
-        vsc = cache["v_scale"].at[bidx, pos].set(vs.astype(jnp.float16))
+        with jax.named_scope("kv_write"):
+            kc = cache["k"].at[bidx, pos].set(kq)
+            vc = cache["v"].at[bidx, pos].set(vq)
+            ksc = cache["k_scale"].at[bidx, pos].set(
+                ks.astype(jnp.float16))
+            vsc = cache["v_scale"].at[bidx, pos].set(
+                vs.astype(jnp.float16))
         out = decode_attention(q, kc, vc, pos + 1,
                                k_scale=ksc, v_scale=vsc)
         new_cache = {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc,
                      "len": pos + 1}
     else:
-        kc = cache["k"].at[bidx, pos].set(k.astype(cache["k"].dtype))
-        vc = cache["v"].at[bidx, pos].set(v.astype(cache["v"].dtype))
+        with jax.named_scope("kv_write"):
+            kc = cache["k"].at[bidx, pos].set(k.astype(cache["k"].dtype))
+            vc = cache["v"].at[bidx, pos].set(v.astype(cache["v"].dtype))
         out = decode_attention(q, kc, vc, pos + 1)
         new_cache = {"k": kc, "v": vc, "len": pos + 1}
     return jnp.einsum("bhk,hkd->bd", out, p["wo"]), new_cache
@@ -321,6 +328,7 @@ def mla_pspecs(cfg: ModelConfig):
     }
 
 
+@jax.named_scope("attention")
 def mla_train(p, x, cfg: ModelConfig, positions):
     m = cfg.mla
     nope, rope_d = m.qk_nope_head_dim, m.qk_rope_head_dim
@@ -342,6 +350,7 @@ def mla_train(p, x, cfg: ModelConfig, positions):
     return jnp.einsum("bthk,hkd->btd", out, p["wo"]), (ckv, k_rope[:, :, 0])
 
 
+@jax.named_scope("attention")
 def mla_decode(p, x, cfg: ModelConfig, cache):
     """Absorbed-matrix MLA decode against the *compressed* latent cache.
 
@@ -361,8 +370,10 @@ def mla_decode(p, x, cfg: ModelConfig, cache):
     kr_t = apply_rope((x @ p["wkr"])[:, None, None, :],
                       pos[:, None], cfg, dim=rope_d)[:, 0, 0]
     bidx = jnp.arange(B)
-    ckv = cache["ckv"].at[bidx, pos].set(ckv_t.astype(cache["ckv"].dtype))
-    kr = cache["kr"].at[bidx, pos].set(kr_t.astype(cache["kr"].dtype))
+    with jax.named_scope("kv_write"):
+        ckv = cache["ckv"].at[bidx, pos].set(
+            ckv_t.astype(cache["ckv"].dtype))
+        kr = cache["kr"].at[bidx, pos].set(kr_t.astype(cache["kr"].dtype))
     # absorb W_uk:   q_lat (B,H,r)
     q_lat = jnp.einsum("bhk,rhk->bhr", q_nope, p["wuk"])
     s = (jnp.einsum("bhr,bsr->bhs", q_lat, ckv,

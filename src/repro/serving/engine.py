@@ -17,6 +17,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models.model import Model
@@ -28,6 +29,7 @@ class Request:
     prompt: np.ndarray              # (T,) int32
     max_new_tokens: int = 16
     submitted_s: float = 0.0
+    admitted_s: Optional[float] = None  # popped off the queue
     first_token_s: Optional[float] = None
     done_s: Optional[float] = None
     output: list = dataclasses.field(default_factory=list)
@@ -107,6 +109,7 @@ class EngineStats:
     wall_s: float = 0.0
     preemptions: int = 0
     swapped_pages: int = 0          # device pages moved host-ward
+    host_syncs: int = 0             # device->host reads (``_fetch``)
     # False when the run hit ``max_steps`` with work still queued or
     # in flight — a truncated sim must never masquerade as a complete
     # one (pair with ``unfinished_uids()`` to censor the report)
@@ -276,24 +279,39 @@ class ServingEngine:
                      if r is not None)
         return len(t._free) >= need + growth
 
+    def _fetch(self, x, dtype=None) -> np.ndarray:
+        """Device array ``x`` on the host. Every device->host read of the
+        jitted serving path goes through here and is counted in
+        ``stats.host_syncs``: the host waits for the device at each."""
+        self.stats.host_syncs += 1
+        return np.asarray(x, dtype)
+
     def _admit(self):
         for slot in range(self.slots):
             if self.slot_req[slot] is not None or not self.queue:
                 continue
+            req = self.queue[0]
             if self._table is not None:
-                if not self._can_admit(self.queue[0]):
+                if not self._can_admit(req):
                     # defer admission — the request stays queued until
                     # retirements free enough pages for its max length
                     self.deferred_admissions += 1
                     return
-                if not self._table.alloc_seq(
-                        slot, len(self.queue[0].prompt)):
+                if not self._table.alloc_seq(slot, len(req.prompt)):
                     raise RuntimeError(       # _can_admit guarantees it
                         "shadow KV table out of pages at admission")
-            req = self.queue.popleft()
+            with TraceAnnotation("engine.admit", uid=req.uid, slot=slot,
+                                 prompt_len=len(req.prompt)):
+                self.queue.popleft()
+                req.admitted_s = time.perf_counter()
+                self._admit_one(req, slot)
+
+    def _admit_one(self, req: Request, slot: int):
+        with TraceAnnotation("engine.prefill", uid=req.uid):
             cache1, logits = self._prefill1(
                 self.params, {"tokens": jnp.asarray(req.prompt[None])})
-            self.stats.prefills += 1
+        self.stats.prefills += 1
+        with TraceAnnotation("engine.splice", uid=req.uid, slot=slot):
             # splice the single-seq cache into this slot
             self.cache = jax.tree.map(
                 lambda full, one: (
@@ -303,27 +321,29 @@ class ServingEngine:
                 self.cache, cache1)
             self.cache["len"] = self.cache["len"].at[slot].set(
                 cache1["len"][0])
-            tok = int(jnp.argmax(logits[0, :self.cfg.vocab_size]))
-            req.first_token_s = time.perf_counter()
-            req.output.append(tok)
-            self._next_tokens[slot] = tok
-            self._remaining[slot] = req.max_new_tokens - 1
-            self.slot_req[slot] = req
-            self.stats.tokens_out += 1
-            if self._table is not None:
-                if not self._table.note_tokens(
-                        slot, int(self.cache["len"][slot])):
-                    raise RuntimeError("shadow KV table out of pages")
-                self._record(PlanRecord(
-                    "prefill", self.stats.decode_steps, (slot,),
-                    (req.uid,),
-                    self._table.prefill_plan(
-                        slot, len(req.prompt),
-                        n_q_heads=self.cfg.n_heads,
-                        d_model=self.cfg.d_model, d_ff=self.cfg.d_ff,
-                        n_layers=self.cfg.n_layers),
-                    arrival_event=req.arrival_event,
-                    n_tokens=len(req.prompt)))
+        with TraceAnnotation("engine.first_token", uid=req.uid):
+            tok = int(self._fetch(
+                jnp.argmax(logits[0, :self.cfg.vocab_size])))
+        req.first_token_s = time.perf_counter()
+        req.output.append(tok)
+        self._next_tokens[slot] = tok
+        self._remaining[slot] = req.max_new_tokens - 1
+        self.slot_req[slot] = req
+        self.stats.tokens_out += 1
+        if self._table is not None:
+            if not self._table.note_tokens(
+                    slot, int(self._fetch(self.cache["len"][slot]))):
+                raise RuntimeError("shadow KV table out of pages")
+            self._record(PlanRecord(
+                "prefill", self.stats.decode_steps, (slot,),
+                (req.uid,),
+                self._table.prefill_plan(
+                    slot, len(req.prompt),
+                    n_q_heads=self.cfg.n_heads,
+                    d_model=self.cfg.d_model, d_ff=self.cfg.d_ff,
+                    n_layers=self.cfg.n_layers),
+                arrival_event=req.arrival_event,
+                n_tokens=len(req.prompt)))
 
     def _retire(self, slot: int):
         req = self.slot_req[slot]
@@ -335,7 +355,21 @@ class ServingEngine:
             self._table.free_seq(slot)
 
     def step(self):
-        """One engine iteration: admit + one batched decode step."""
+        """One engine iteration: admit + one batched decode step.
+
+        Host spans (``jax.profiler.TraceAnnotation``, recorded only
+        while a profiler trace runs): ``engine.step`` holds the
+        ``engine.admit`` of each admission (``engine.prefill``,
+        ``engine.splice``, ``engine.first_token``), then
+        ``engine.decode``, ``engine.sample`` and ``engine.retire``.
+        Spans of one step share ``step`` (``stats.decode_steps`` at
+        entry), spans of one request share ``uid``."""
+        idx = self.stats.decode_steps
+        with TraceAnnotation("engine.step", step=idx,
+                             active=self.slots - self.slot_req.count(None)):
+            return self._step(idx)
+
+    def _step(self, idx: int) -> bool:
         self._admit()
         active = [i for i, r in enumerate(self.slot_req) if r is not None]
         if not active:
@@ -344,32 +378,39 @@ class ServingEngine:
             # the step streams each active slot's currently-resident KV
             # pages; the new token's KV lands before the next step
             self._record(PlanRecord(
-                "decode", self.stats.decode_steps, tuple(active),
+                "decode", idx, tuple(active),
                 tuple(self.slot_req[s].uid for s in active),
                 self._table.decode_step_plan(
                     active, n_q_heads=self.cfg.n_heads,
                     n_layers=self.cfg.n_layers)))
-        toks = jnp.asarray(self._next_tokens)
-        self.cache, logits = self._decode(self.params, self.cache, toks)
+        with TraceAnnotation("engine.decode", step=idx):
+            toks = jnp.asarray(self._next_tokens)
+            self.cache, logits = self._decode(self.params, self.cache, toks)
         self.stats.decode_steps += 1
         if self._table is not None:
             for slot in active:
                 if not self._table.note_tokens(
-                        slot, int(self.cache["len"][slot])):
+                        slot, int(self._fetch(self.cache["len"][slot]))):
                     raise RuntimeError("shadow KV table out of pages")
-        nxt = np.asarray(jnp.argmax(
-            logits[:, :self.cfg.vocab_size], axis=-1), np.int32)
-        for slot in active:
-            req = self.slot_req[slot]
-            tok = int(nxt[slot])
-            req.output.append(tok)
-            self.stats.tokens_out += 1
-            self._next_tokens[slot] = tok
-            self._remaining[slot] -= 1
-            hit_eos = self.eos is not None and tok == self.eos
-            if self._remaining[slot] <= 0 or hit_eos or \
-                    int(self.cache["len"][slot]) >= self.max_seq - 1:
-                self._retire(slot)
+        with TraceAnnotation("engine.sample", step=idx):
+            nxt = self._fetch(jnp.argmax(
+                logits[:, :self.cfg.vocab_size], axis=-1), np.int32)
+        with TraceAnnotation("engine.retire", step=idx) as span:
+            retired = 0
+            for slot in active:
+                req = self.slot_req[slot]
+                tok = int(nxt[slot])
+                req.output.append(tok)
+                self.stats.tokens_out += 1
+                self._next_tokens[slot] = tok
+                self._remaining[slot] -= 1
+                hit_eos = self.eos is not None and tok == self.eos
+                if self._remaining[slot] <= 0 or hit_eos or int(
+                        self._fetch(self.cache["len"][slot])) >= \
+                        self.max_seq - 1:
+                    self._retire(slot)
+                    retired += 1
+            span.set_metadata(retired=retired)
         return True
 
     def run_until_drained(self, max_steps: int = 10_000) -> EngineStats:
