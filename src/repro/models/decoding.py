@@ -130,17 +130,33 @@ def cache_axes(cfg: ModelConfig):
 # =====================================================================
 # decode bodies
 # =====================================================================
+def write_token(stack, new, pos, layer=None):
+    """Write each slot's new cache entries at ``[layer, b, pos[b]]`` of
+    the stacked ``(L,B,S,...)`` cache leaves: one dynamic-update-slice
+    per slot and leaf, in place when the cache is donated. ``new``
+    leaves are ``(L,B,...)`` for every layer (``layer=None``), or
+    ``(B,...)`` for the one layer ``layer``. A position past the end is
+    clamped to the last one; only a free slot's length gets there."""
+    def put(c, n):
+        if layer is not None:
+            n = n[None]
+        for b in range(pos.shape[0]):
+            start = (0 if layer is None else layer, b, pos[b])
+            c = jax.lax.dynamic_update_slice(
+                c, n[:, b, None, None], start + (0,) * (c.ndim - 3))
+        return c
+    with jax.named_scope("attention"), jax.named_scope("kv_write"):
+        return jax.tree.map(put, stack, new)
+
+
 def _attn_decode_layer(lp, x, cfg, cache_slice, pos):
-    """Single-token attention+FFN for one layer. cache_slice: this layer's
-    k/v (B,S,KH,hd) (or MLA latents). Returns (x', new_slice)."""
+    """Single-token attention for one layer. cache_slice: this layer's
+    k/v (B,S,KH,hd) (or MLA latents), only read. Returns (x', new): the
+    token's own entries, (B,KH,hd) per leaf."""
     x = shard(x, ("batch", "embed_act"))
     h = L.apply_norm(lp["ln1"], x, cfg)
-    if cfg.mla:
-        a, new = L.mla_decode(lp["attn"], h, cfg, {**cache_slice, "len": pos})
-    else:
-        a, new = L.attention_decode(lp["attn"], h, cfg,
-                                    {**cache_slice, "len": pos})
-    new.pop("len")
+    attend = L.mla_decode if cfg.mla else L.attention_decode
+    a, new = attend(lp["attn"], h, cfg, {**cache_slice, "len": pos})
     return x + a, new
 
 
@@ -158,36 +174,42 @@ def _moe_decode_layer(lp, x, cfg, cache_slice, pos):
     return x + y[:, 0], new
 
 
+def _decode_stack(layer_fn, x, cfg, layer_params, stack, pos):
+    """Scan ``layer_fn`` over a stacked attention cache. The scan reads
+    each layer's cache and emits only the new token's entries, which
+    ``write_token`` then writes into the stack once, after the scan.
+    Returns (x, the updated stack)."""
+    def body(x, xs):
+        lp, cs = xs
+        return layer_fn(lp, x, cfg, cs, pos)
+    x, new = jax.lax.scan(body, x, (layer_params, stack))
+    return x, write_token(stack, new, pos)
+
+
 def decode_step(params, cfg: ModelConfig, cache, tokens):
     """tokens: (B,) int32 (or (B,d) embeddings for pure-embed families).
-    Returns (new_cache, logits (B, V))."""
+    Returns (new_cache, logits (B, V)).
+
+    A stacked attention cache is read where attention needs it and
+    written once, at each slot's new position: jit with the cache
+    donated and that write happens in place."""
     fam = cfg.family
     x = params["embed"][tokens].astype(jnp.bfloat16)
     pos = cache["len"]
     new_cache = {"len": pos + 1}
 
     if fam in ("dense", "vlm"):
-        def body(x, xs):
-            lp, cs = xs
-            y, new = _dense_decode_layer(lp, x, cfg, cs, pos)
-            return y, new
-        x, new = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
-        new_cache["layers"] = new
+        x, new_cache["layers"] = _decode_stack(
+            _dense_decode_layer, x, cfg, params["layers"], cache["layers"],
+            pos)
     elif fam == "moe":
         if cfg.moe.first_dense_layers:
-            def dbody(x, xs):
-                lp, cs = xs
-                y, new = _dense_decode_layer(lp, x, cfg, cs, pos)
-                return y, new
-            x, newd = jax.lax.scan(dbody, x, (params["dense_layers"],
-                                              cache["dense_layers"]))
-            new_cache["dense_layers"] = newd
-        def body(x, xs):
-            lp, cs = xs
-            y, new = _moe_decode_layer(lp, x, cfg, cs, pos)
-            return y, new
-        x, new = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
-        new_cache["layers"] = new
+            x, new_cache["dense_layers"] = _decode_stack(
+                _dense_decode_layer, x, cfg, params["dense_layers"],
+                cache["dense_layers"], pos)
+        x, new_cache["layers"] = _decode_stack(
+            _moe_decode_layer, x, cfg, params["layers"], cache["layers"],
+            pos)
     elif fam == "hybrid":
         shared = params["shared_attn"]
         every = cfg.ssm.attn_every
@@ -203,9 +225,8 @@ def decode_step(params, cfg: ModelConfig, cache, tokens):
                 a, new = L.attention_decode(
                     shared["attn"], h, cfg,
                     {"k": sk[inv], "v": sv[inv], "len": pos})
-                sk = jax.lax.dynamic_update_index_in_dim(sk, new["k"], inv, 0)
-                sv = jax.lax.dynamic_update_index_in_dim(sv, new["v"], inv, 0)
-                return x + a, sk, sv, inv + 1
+                kv = write_token({"k": sk, "v": sv}, new, pos, inv)
+                return x + a, kv["k"], kv["v"], inv + 1
 
             x, sk, sv, inv = jax.lax.cond(
                 idx % every == 0, with_attn, lambda op: op, (x, sk, sv, inv))
@@ -250,7 +271,7 @@ def decode_step(params, cfg: ModelConfig, cache, tokens):
         x, new = jax.lax.scan(body, x, (params["layers"], cache["layers"],
                                         cache["cross"]["k"],
                                         cache["cross"]["v"]))
-        new_cache["layers"] = new
+        new_cache["layers"] = write_token(cache["layers"], new, pos)
         new_cache["cross"] = cache["cross"]
     else:
         raise ValueError(fam)

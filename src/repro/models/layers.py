@@ -145,20 +145,39 @@ def chunked_attention(q, k, v, *, causal: bool, q_offset=0,
     return out[:, :Tq].astype(v.dtype)
 
 
+def softmax_with_new(s, valid, s_new):
+    """Softmax over the valid cached keys and one new key.
+
+    s: (..., S) f32 scores against the cache, valid broadcastable to s;
+    s_new: (...) f32 score against the current token's own key. Returns
+    (p (..., S), p_new (...)): ``jax.nn.softmax`` over the S + 1 keys."""
+    s = jnp.where(valid, s, NEG_INF)
+    m = jnp.maximum(s.max(-1), s_new)
+    e = jnp.exp(s - m[..., None])
+    e_new = jnp.exp(s_new - m)
+    total = e.sum(-1) + e_new
+    return e / total[..., None], e_new / total
+
+
 def decode_attention(q, k_cache, v_cache, cache_len, k_scale=None,
-                     v_scale=None):
+                     v_scale=None, new=None):
     """Single-token attention against a (padded) cache.
 
     q: (B, H, D); caches: (B, S, KH, D); cache_len: () or (B,) int32.
     k_scale/v_scale: (B, S, KH) dequant scales for INT8 caches.
+    new: the current token's own entries, attended as one more key after
+    the ``cache_len`` cached ones: {"k","v": (B, KH, D)}, with
+    "k_scale","v_scale" (B, KH) beside INT8 caches.
     """
     B, H, D = q.shape
     _, S, KH, Dv = v_cache.shape
     G = H // KH
     qg = q.reshape(B, KH, G, D)
-    s = jnp.einsum("bhgd,bkhd->bhgk", qg,
-                   k_cache.astype(jnp.bfloat16)
-                   if k_cache.dtype == jnp.int8 else k_cache,
+
+    def mxu(a):
+        return a.astype(jnp.bfloat16) if a.dtype == jnp.int8 else a
+
+    s = jnp.einsum("bhgd,bkhd->bhgk", qg, mxu(k_cache),
                    preferred_element_type=jnp.float32) / math.sqrt(D)
     if k_scale is not None:
         s = s * k_scale.astype(jnp.float32).transpose(0, 2, 1)[:, :, None]
@@ -167,17 +186,32 @@ def decode_attention(q, k_cache, v_cache, cache_len, k_scale=None,
     cl = jnp.asarray(cache_len)
     if cl.ndim == 0:
         cl = jnp.broadcast_to(cl, (B,))
-    valid = pos[None, :] < cl[:, None]
-    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
+    valid = (pos[None, :] < cl[:, None])[:, None, None, :]
+    if new is None:
+        p = jax.nn.softmax(jnp.where(valid, s, NEG_INF), axis=-1)
+    else:
+        s_new = jnp.einsum("bhgd,bhd->bhg", qg, mxu(new["k"]),
+                           preferred_element_type=jnp.float32) / math.sqrt(D)
+        if k_scale is not None:
+            s_new = s_new * new["k_scale"].astype(jnp.float32)[:, :, None]
+        p, p_new = softmax_with_new(s, valid, s_new)
     if v_scale is not None:
         pv = p * v_scale.astype(jnp.float32).transpose(0, 2, 1)[:, :, None]
         out = jnp.einsum("bhgk,bkhd->bhgd", pv.astype(jnp.bfloat16),
                          v_cache.astype(jnp.bfloat16),
                          preferred_element_type=jnp.float32)
+        if new is not None:
+            pv_new = p_new * new["v_scale"].astype(jnp.float32)[:, :, None]
+            out = out + jnp.einsum(
+                "bhg,bhd->bhgd", pv_new.astype(jnp.bfloat16),
+                new["v"].astype(jnp.bfloat16),
+                preferred_element_type=jnp.float32)
         return out.reshape(B, H, Dv).astype(jnp.bfloat16)
     out = jnp.einsum("bhgk,bkhd->bhgd", p.astype(v_cache.dtype), v_cache,
                      preferred_element_type=jnp.float32)
+    if new is not None:
+        out = out + jnp.einsum("bhg,bhd->bhgd", p_new.astype(v_cache.dtype),
+                               new["v"], preferred_element_type=jnp.float32)
     return out.reshape(B, H, Dv).astype(v_cache.dtype)
 
 
@@ -252,9 +286,12 @@ def attention_train(p, x, cfg: ModelConfig, positions, causal=True,
 def attention_decode(p, x, cfg: ModelConfig, cache):
     """x: (B, d) one token. cache: {"k","v": (B,S,KH,hd), "len": (B,)}.
 
-    Per-sequence lengths: slot b's new KV lands at its own position —
-    continuous batching serves mixed-progress sequences in one step."""
-    B, d = x.shape
+    Per-sequence lengths: slot b attends to its ``len[b]`` cached
+    positions and to its own new K/V as one more key, so continuous
+    batching serves mixed-progress sequences in one step. The cache is
+    only read. Returns (out (B, d), new): the token's entries in the
+    cache's dtype, {"k","v": (B,KH,hd)} (and "k_scale","v_scale" (B,KH)
+    for INT8 caches), for the caller to write at ``[b, len[b]]``."""
     q = jnp.einsum("bd,dhk->bhk", x, p["wq"])
     k = jnp.einsum("bd,dhk->bhk", x, p["wk"])
     v = jnp.einsum("bd,dhk->bhk", x, p["wv"])
@@ -263,50 +300,23 @@ def attention_decode(p, x, cfg: ModelConfig, cache):
     pos = cache["len"]                                    # (B,)
     q = apply_rope(q[:, None], pos[:, None], cfg)[:, 0]
     k = apply_rope(k[:, None], pos[:, None], cfg)[:, 0]
-    bidx = jnp.arange(B)
     if "k_scale" in cache:
         # INT8 paged KV: per-(token, kv-head) scales — halves the decode
         # bandwidth wall (the paper's INT8 streaming, applied to the KV)
         ks = jnp.max(jnp.abs(k), -1) / 127.0 + 1e-8
         vs = jnp.max(jnp.abs(v), -1) / 127.0 + 1e-8
-        kq = jnp.round(k / ks[..., None]).astype(jnp.int8)
-        vq = jnp.round(v / vs[..., None]).astype(jnp.int8)
-        with jax.named_scope("kv_write"):
-            kc = cache["k"].at[bidx, pos].set(kq)
-            vc = cache["v"].at[bidx, pos].set(vq)
-            ksc = cache["k_scale"].at[bidx, pos].set(
-                ks.astype(jnp.float16))
-            vsc = cache["v_scale"].at[bidx, pos].set(
-                vs.astype(jnp.float16))
-        out = decode_attention(q, kc, vc, pos + 1,
-                               k_scale=ksc, v_scale=vsc)
-        new_cache = {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc,
-                     "len": pos + 1}
+        new = {"k": jnp.round(k / ks[..., None]).astype(jnp.int8),
+               "v": jnp.round(v / vs[..., None]).astype(jnp.int8),
+               "k_scale": ks.astype(jnp.float16),
+               "v_scale": vs.astype(jnp.float16)}
+        out = decode_attention(q, cache["k"], cache["v"], pos,
+                               k_scale=cache["k_scale"],
+                               v_scale=cache["v_scale"], new=new)
     else:
-        with jax.named_scope("kv_write"):
-            kc = cache["k"].at[bidx, pos].set(k.astype(cache["k"].dtype))
-            vc = cache["v"].at[bidx, pos].set(v.astype(cache["v"].dtype))
-        out = decode_attention(q, kc, vc, pos + 1)
-        new_cache = {"k": kc, "v": vc, "len": pos + 1}
-    return jnp.einsum("bhk,hkd->bd", out, p["wo"]), new_cache
-
-
-def init_attention_cache(cfg: ModelConfig, batch: int, seq: int,
-                         dtype=jnp.bfloat16):
-    KH, hd = cfg.n_kv_heads, cfg.resolved_head_dim
-    return {
-        "k": jnp.zeros((batch, seq, KH, hd), dtype),
-        "v": jnp.zeros((batch, seq, KH, hd), dtype),
-        "len": jnp.zeros((), jnp.int32),
-    }
-
-
-def cache_axes(cfg: ModelConfig):
-    return {
-        "k": ("cache_batch", "cache_seq", "kv_heads", "head_dim"),
-        "v": ("cache_batch", "cache_seq", "kv_heads", "head_dim"),
-        "len": (),
-    }
+        new = {"k": k.astype(cache["k"].dtype),
+               "v": v.astype(cache["v"].dtype)}
+        out = decode_attention(q, cache["k"], cache["v"], pos, new=new)
+    return jnp.einsum("bhk,hkd->bd", out, p["wo"]), new
 
 
 # --------------------------------------------------------------- MLA
@@ -354,12 +364,13 @@ def mla_train(p, x, cfg: ModelConfig, positions):
 def mla_decode(p, x, cfg: ModelConfig, cache):
     """Absorbed-matrix MLA decode against the *compressed* latent cache.
 
-    cache: {"ckv": (B,S,r), "kr": (B,S,rope), "len": ()}.
+    cache: {"ckv": (B,S,r), "kr": (B,S,rope), "len": (B,)}, only read:
+    slot b attends to its ``len[b]`` cached latents and its own new one.
     score = q_nope·W_uk·ckv + q_rope·k_rope  (W_uk absorbed into q).
+    Returns (out (B, d), new {"ckv": (B,r), "kr": (B,rope)}).
     """
     m = cfg.mla
-    B = x.shape[0]
-    nope, rope_d, r = m.qk_nope_head_dim, m.qk_rope_head_dim, m.kv_lora_rank
+    nope, rope_d = m.qk_nope_head_dim, m.qk_rope_head_dim
     pos = cache["len"]                                        # (B,)
     cq = apply_norm(p["q_norm"], x @ p["wdq"], cfg)
     q = jnp.einsum("br,rhk->bhk", cq, p["wuq"])
@@ -369,37 +380,25 @@ def mla_decode(p, x, cfg: ModelConfig, cache):
     ckv_t = apply_norm(p["kv_norm"], x @ p["wdkv"], cfg)       # (B,r)
     kr_t = apply_rope((x @ p["wkr"])[:, None, None, :],
                       pos[:, None], cfg, dim=rope_d)[:, 0, 0]
-    bidx = jnp.arange(B)
-    with jax.named_scope("kv_write"):
-        ckv = cache["ckv"].at[bidx, pos].set(
-            ckv_t.astype(cache["ckv"].dtype))
-        kr = cache["kr"].at[bidx, pos].set(kr_t.astype(cache["kr"].dtype))
+    ckv, kr = cache["ckv"], cache["kr"]
+    new = {"ckv": ckv_t.astype(ckv.dtype), "kr": kr_t.astype(kr.dtype)}
     # absorb W_uk:   q_lat (B,H,r)
     q_lat = jnp.einsum("bhk,rhk->bhr", q_nope, p["wuk"])
+    norm = math.sqrt(nope + rope_d)
     s = (jnp.einsum("bhr,bsr->bhs", q_lat, ckv,
                     preferred_element_type=jnp.float32)
          + jnp.einsum("bhk,bsk->bhs", q_rope, kr,
-                      preferred_element_type=jnp.float32))
-    s = s / math.sqrt(nope + rope_d)
-    valid = jnp.arange(ckv.shape[1])[None, :] < (pos + 1)[:, None]
-    s = jnp.where(valid[:, None, :], s, NEG_INF)
-    pattn = jax.nn.softmax(s, axis=-1)
-    o_lat = jnp.einsum("bhs,bsr->bhr", pattn.astype(ckv.dtype), ckv)
+                      preferred_element_type=jnp.float32)) / norm
+    s_new = (jnp.einsum("bhr,br->bh", q_lat, new["ckv"],
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bhk,bk->bh", q_rope, new["kr"],
+                          preferred_element_type=jnp.float32)) / norm
+    valid = jnp.arange(ckv.shape[1])[None, :] < pos[:, None]
+    pattn, p_new = softmax_with_new(s, valid[:, None, :], s_new)
+    o_lat = (jnp.einsum("bhs,bsr->bhr", pattn.astype(ckv.dtype), ckv,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bh,br->bhr", p_new.astype(ckv.dtype), new["ckv"],
+                          preferred_element_type=jnp.float32)
+             ).astype(ckv.dtype)
     out = jnp.einsum("bhr,rhk->bhk", o_lat, p["wuv"])
-    new_cache = {"ckv": ckv, "kr": kr, "len": pos + 1}
-    return jnp.einsum("bhk,hkd->bd", out, p["wo"]), new_cache
-
-
-def init_mla_cache(cfg: ModelConfig, batch: int, seq: int,
-                   dtype=jnp.bfloat16):
-    m = cfg.mla
-    return {
-        "ckv": jnp.zeros((batch, seq, m.kv_lora_rank), dtype),
-        "kr": jnp.zeros((batch, seq, m.qk_rope_head_dim), dtype),
-        "len": jnp.zeros((), jnp.int32),
-    }
-
-
-def mla_cache_axes(cfg: ModelConfig):
-    return {"ckv": ("cache_batch", "cache_seq", "lora"),
-            "kr": ("cache_batch", "cache_seq", "head_dim"), "len": ()}
+    return jnp.einsum("bhk,hkd->bd", out, p["wo"]), new

@@ -8,6 +8,7 @@ persistent compilation cache is off around these compiles, because an
 entry written for a described chip cannot be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,7 @@ from repro.kernels import ops
 from repro.models.model import Model
 
 B, H, KH, D, PAGE, MAX_PAGES = 8, 14, 2, 64, 8, 256   # 8 slots x 2048
+SEQ = PAGE * MAX_PAGES
 
 
 @pytest.fixture(scope="module")
@@ -75,13 +77,28 @@ def test_paged_attention_compiles(one_chip):
     _assert_kernel(compiled)
 
 
-def test_qwen2_decode_step_compiles(one_chip):
+def _qwen2_decode(one_chip, **jit_kw):
     model = Model(get_config("qwen2-0.5b"), remat="none")
     place = lambda tree: jax.tree.map(  # noqa: E731
         lambda a: _spec(one_chip, a.shape, a.dtype), tree)
     params = place(model.abstract_params())
-    cache = place(jax.eval_shape(lambda: model.init_cache(B, 2048)))
-    compiled = jax.jit(model.decode_step).lower(
+    cache = place(jax.eval_shape(lambda: model.init_cache(B, SEQ)))
+    return jax.jit(model.decode_step, **jit_kw).lower(
         params, cache, _spec(one_chip, (B,), jnp.int32)).compile()
-    mem = compiled.memory_analysis()
+
+
+def test_qwen2_decode_step_compiles(one_chip):
+    mem = _qwen2_decode(one_chip).memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_qwen2_decode_step_copies_no_cache_when_donated(one_chip):
+    """With the cache donated, attention reads each layer's K/V as it
+    lies and the new token is written in place: no copy in the program
+    has the cache's sequence length, and no temporary is cache-sized."""
+    compiled = _qwen2_decode(one_chip, donate_argnums=(1,))
+    copies = re.findall(r"\[([\d,]+)\]\{[^}]*\} copy\(", compiled.as_text())
+    assert copies and not [c for c in copies
+                           if str(SEQ) in c.split(",")]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < mem.alias_size_in_bytes / 100
