@@ -1,8 +1,10 @@
 """The one place the benchmark touches the program under test.
 
 It builds the program's ``ModelConfig`` from a configuration file's
-``model`` group, hands the program the benchmark's own weights, and
-makes the serving engine. Everything else in the benchmark works on
+``model`` group, through the group's family (``families/<family>.py``,
+which is handed ``repro.configs.base`` and imports nothing of the
+program), hands the program the benchmark's own weights, and makes the
+serving engine. Everything else in the benchmark works on
 plain data.
 """
 from __future__ import annotations
@@ -12,12 +14,13 @@ from pathlib import Path
 
 import jax
 
-from chipbench import weights
+from chipbench import spec, weights
 
 SRC = Path(__file__).resolve().parents[1] / "src"
 if str(SRC) not in sys.path:
     sys.path.insert(0, str(SRC))
 
+from repro.configs import base as program_base  # noqa: E402
 from repro.configs.base import ModelConfig  # noqa: E402
 from repro.models.model import Model  # noqa: E402
 from repro.serving.engine import Request, ServingEngine  # noqa: E402,F401
@@ -27,28 +30,15 @@ PROGRAM_NORM_EPS = 1e-5
 
 
 def model_config(name: str, m: dict) -> ModelConfig:
-    """The program's config for model group ``m``. Raises where the
-    program cannot run what the group states."""
+    """The program's config for model group ``m``, as its family builds
+    it. Raises where the program cannot run what the group states."""
     if m["dtype"] != "bfloat16":
         raise ValueError(f"{name}: the program serves bfloat16 only")
     if m["norm_eps"] != PROGRAM_NORM_EPS:
         raise ValueError(f"{name}: the program's RMSNorm epsilon is "
                          f"{PROGRAM_NORM_EPS}, the config states "
                          f"{m['norm_eps']}")
-    if m["rotary_dim"] == m["head_dim"]:
-        rope = "full"
-    elif 2 * m["rotary_dim"] == m["head_dim"]:
-        rope = "2d"
-    else:
-        raise ValueError(f"{name}: rotary_dim {m['rotary_dim']} is "
-                         f"neither head_dim nor half of it")
-    cfg = ModelConfig(
-        name=name, family="dense", n_layers=m["layers"],
-        d_model=m["d_model"], n_heads=m["heads"], n_kv_heads=m["kv_heads"],
-        d_ff=m["d_ff"], vocab_size=m["vocab"], head_dim=m["head_dim"],
-        qkv_bias=m["qkv_bias"], rope=rope, rope_theta=m["rope_theta"],
-        tie_embeddings=m["tied_embeddings"],
-        vocab_pad_multiple=m.get("vocab_pad_multiple", 256))
+    cfg = spec.family_of(m).program_config(name, m, program_base)
     if cfg.padded_vocab != m["vocab_padded"]:
         raise ValueError(f"{name}: the program pads the vocabulary to "
                          f"{cfg.padded_vocab}, not {m['vocab_padded']}")

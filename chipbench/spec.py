@@ -8,10 +8,16 @@ benchmark's directory, found by that name:
 - ``traffic/<traffic>.json``: the traffic mix;
 - ``cells/<workload>.json``: the cell's engine settings and limits;
 - ``metrics/<metric>.py``: the reader of one metric;
-- ``references/<reference>.py``: a configuration's plain reference.
+- ``references/<reference>.py``: a configuration's plain reference;
+- ``families/<family>.py``: an architecture, named by the ``family`` of
+  a configuration's ``model`` group: its weight layout
+  (``leaf_specs``), the program's config for it (``program_config``),
+  the least work of a decode step and a prefill (``decode_step``,
+  ``prefill``), and what a published configuration implies for the
+  model group beyond the keys its file maps (``check_published``).
 
-A new configuration, mix, cell or metric is added as new files and
-entries; no existing file changes.
+A new configuration, architecture, mix, cell or metric is added as new
+files and entries; no existing file changes.
 """
 from __future__ import annotations
 
@@ -23,6 +29,10 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
 METRIC_KEYS = ("UNIT", "SOURCE", "BETTER", "read")
 LAYER_KEYS = ("LAYER", "MOVES")
+FAMILY_KEYS = ("leaf_specs", "program_config", "decode_step", "prefill",
+               "check_published")
+
+_FAMILIES: dict = {}     # family name -> its module
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -56,7 +66,15 @@ def workload(bench: dict, name: str) -> dict:
 
 
 def config(name: str, base: Path = HERE) -> dict:
-    return _json(base, "configs", name)
+    """``configs/<name>.json`` under ``base``, with the family its model
+    group names loaded from ``base`` (``family``)."""
+    cfg = _json(base, "configs", name)
+    if "family" not in cfg.get("model", {}):
+        raise KeyError(f"{Path(base) / 'configs' / f'{name}.json'}: its "
+                       f"model group names no 'family' (a file "
+                       f"families/<family>.py)")
+    family(cfg["model"]["family"], base)
+    return cfg
 
 
 def traffic(name: str, base: Path = HERE) -> dict:
@@ -69,6 +87,35 @@ def cell(name: str, base: Path = HERE) -> dict:
 
 def reference(name: str, base: Path = HERE):
     return _module(base, "references", name)
+
+
+def family(name: str, base: Path = HERE):
+    """The architecture family ``families/<name>.py`` under ``base``.
+
+    ``family_of`` finds a family by its name alone, so a name stands for
+    one file's code in a process: a copy of the same code under another
+    base is the module already loaded, and other code under a name that
+    is taken raises."""
+    path = Path(base) / "families" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no families file {path}")
+    mod = _FAMILIES.get(name)
+    if mod is None:
+        mod = _module(base, "families", name)
+        missing = [k for k in FAMILY_KEYS if not hasattr(mod, k)]
+        if missing:
+            raise AttributeError(f"family {path} lacks {missing}")
+        _FAMILIES[name] = mod
+    elif Path(mod.__file__).read_bytes() != path.read_bytes():
+        raise ValueError(f"family {name!r} is loaded from {mod.__file__}; "
+                         f"{path} holds other code under its name")
+    return mod
+
+
+def family_of(m: dict):
+    """The family that model group ``m`` names: as ``config`` loaded it,
+    else ``families/<family>.py`` here."""
+    return _FAMILIES.get(m["family"]) or family(m["family"])
 
 
 def metric(entry: dict, base: Path = HERE):

@@ -1,4 +1,4 @@
-"""Seeded random weights of a dense GQA decoder, made by the benchmark.
+"""Seeded random weights of a configuration, made by the benchmark.
 
 The program under test is handed these weights; the reference makes
 the same numbers again, one layer at a time, from the same seed. So
@@ -6,8 +6,9 @@ the reference takes nothing that the program has made.
 
 Every leaf is drawn from its own key, ``fold_in(root, crc32(path))``,
 and every layer of a stacked leaf from ``fold_in(leaf_key, layer)``.
-The layout (paths, shapes, dtypes) is the one the program's parameter
-tree has; ``program.make_params`` checks that they agree.
+The layout (paths, shapes, dtypes) is the configuration's family's
+(``families/<family>.py``), the one the program's parameter tree has;
+``program.make_params`` checks that they agree.
 """
 from __future__ import annotations
 
@@ -17,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import spec
+
 
 def root_key(seed: int):
     """A JAX key from any whole number, however large."""
@@ -25,36 +28,14 @@ def root_key(seed: int):
 
 
 def leaf_specs(m: dict) -> dict:
-    """path -> (shape, dtype, law, scale, stacked) for model group ``m``.
+    """path -> (shape, dtype, law, scale, stacked) for model group ``m``,
+    as its family lays the weights out.
 
     Laws: ``normal`` (N(0,1) times scale), ``norm`` (1 + 0.1 N, a norm's
-    gain), ``bias`` (0.02 N). Matrices are scaled by fan-in ** -0.5.
-    Stacked leaves carry the layer count as their first dimension.
+    gain), ``bias`` (0.02 N). Stacked leaves carry the layer count as
+    their first dimension.
     """
-    L, d, H, KH = m["layers"], m["d_model"], m["heads"], m["kv_heads"]
-    hd, f, Vp = m["head_dim"], m["d_ff"], m["vocab_padded"]
-    bf, f32 = "bfloat16", "float32"
-    s = {
-        "embed": ((Vp, d), bf, "normal", 0.02, False),
-        "final_norm/scale": ((d,), f32, "norm", 1.0, False),
-        "layers/ln1/scale": ((L, d), f32, "norm", 1.0, True),
-        "layers/ln2/scale": ((L, d), f32, "norm", 1.0, True),
-        "layers/attn/wq": ((L, d, H, hd), bf, "normal", d ** -0.5, True),
-        "layers/attn/wk": ((L, d, KH, hd), bf, "normal", d ** -0.5, True),
-        "layers/attn/wv": ((L, d, KH, hd), bf, "normal", d ** -0.5, True),
-        "layers/attn/wo": ((L, H, hd, d), bf, "normal", (H * hd) ** -0.5,
-                           True),
-        "layers/mlp/wi_gate": ((L, d, f), bf, "normal", d ** -0.5, True),
-        "layers/mlp/wi_up": ((L, d, f), bf, "normal", d ** -0.5, True),
-        "layers/mlp/wo": ((L, f, d), bf, "normal", f ** -0.5, True),
-    }
-    if m["qkv_bias"]:
-        s["layers/attn/bq"] = ((L, H, hd), bf, "bias", 1.0, True)
-        s["layers/attn/bk"] = ((L, KH, hd), bf, "bias", 1.0, True)
-        s["layers/attn/bv"] = ((L, KH, hd), bf, "bias", 1.0, True)
-    if not m["tied_embeddings"]:
-        s["lm_head"] = ((d, Vp), bf, "normal", d ** -0.5, False)
-    return s
+    return spec.family_of(m).leaf_specs(m)
 
 
 def _draw(key, shape, dtype, law, scale):

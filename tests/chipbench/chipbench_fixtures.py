@@ -10,6 +10,7 @@ ROOT = Path(__file__).resolve().parents[2]
 # qwen2-0.5b's reduced preset (configs/qwen2_0_5b.reduced()) as a model
 # group: 2 layers, width 64, 4 heads over 2 KV groups.
 TINY_MODEL = {
+    "family": "dense_gqa",
     "layers": 2, "d_model": 64, "heads": 4, "kv_heads": 2, "head_dim": 16,
     "d_ff": 128, "vocab": 256, "vocab_padded": 256, "vocab_pad_multiple": 16,
     "rope_theta": 10000.0, "rotary_dim": 16, "norm_eps": 1e-5,

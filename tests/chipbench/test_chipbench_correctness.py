@@ -3,6 +3,8 @@ at a size a test run can hold, and a run whose timed path is broken
 underneath comes out not correct."""
 import time
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -38,16 +40,26 @@ def _altered_token(eng):
 
 
 def _state_unchanged(eng):
-    """The decode step hands back the cache it was given."""
+    """The decode step hands back the cache as it was before the step: a
+    copy taken before the call, which holds whether or not the step
+    donates (and so deletes) the cache it is given."""
     decode = eng._decode
 
     def bad(p, cache, toks):
+        before = jax.tree.map(jnp.copy, cache)
         _, logits = decode(p, cache, toks)
-        return cache, logits
+        return before, logits
     eng._decode = bad
 
 
-@pytest.mark.parametrize("fault", [_altered_token, _state_unchanged])
+def _state_unchanged_donated(eng):
+    """As ``_state_unchanged``, on a decode step that donates its cache."""
+    eng._decode = jax.jit(eng.model.decode_step, donate_argnums=(1,))
+    _state_unchanged(eng)
+
+
+@pytest.mark.parametrize("fault", [_altered_token, _state_unchanged,
+                                   _state_unchanged_donated])
 def test_a_broken_timed_path_is_not_correct(tiny_bench, monkeypatch,
                                             fault):
     root, base = tiny_bench

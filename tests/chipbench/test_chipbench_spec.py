@@ -14,19 +14,24 @@ BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 
-def test_top_level_keys_and_names():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+def check_names(bench):
+    """The top-level keys, and every name, of a BENCHMARK.json."""
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
     names = [x["name"] for k in ("configs", "workloads", "end_to_end",
-                                 "per_layer") for x in BENCH[k]]
+                                 "per_layer") for x in bench[k]]
     assert all(NAME.match(n) for n in names)
     for k in ("configs", "workloads"):
-        assert len({x["name"] for x in BENCH[k]}) == len(BENCH[k])
-    assert len({m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]}
-               ) == len(BENCH["end_to_end"]) + len(BENCH["per_layer"])
-    for w in BENCH["workloads"]:
+        assert len({x["name"] for x in bench[k]}) == len(bench[k])
+    assert len({m["name"] for m in bench["end_to_end"] + bench["per_layer"]}
+               ) == len(bench["end_to_end"]) + len(bench["per_layer"])
+    for w in bench["workloads"]:
         assert len(w["why"]) <= 200 and w["chips"] in (1, 4)
-    assert 1 <= BENCH["run_seconds"] <= 51
+    assert 1 <= bench["run_seconds"] <= 51
+
+
+def test_top_level_keys_and_names():
+    check_names(BENCH)
 
 
 @pytest.mark.parametrize("w", BENCH["workloads"], ids=lambda w: w["name"])
@@ -57,34 +62,28 @@ def test_every_metric_has_a_reader_that_agrees(m):
         assert m["source"] in ("host_clock", "device_trace")
 
 
-@pytest.mark.parametrize("c", BENCH["configs"], ids=lambda c: c["name"])
-def test_model_group_follows_the_published_keys(c):
-    f = json.loads((ROOT / c["file"]).read_text())
+def check_model_group(c, root=ROOT, base=spec.HERE):
+    """Configuration entry ``c`` of the BENCHMARK.json at ``root``: its
+    file agrees with the entry, its model group with each published key
+    that the file's ``published_keys`` maps and with what its family
+    reads from the published file, and the program takes the group."""
+    f = json.loads((root / c["file"]).read_text())
+    assert spec.config(c["name"], base) == f
     m = f["model"]
     assert f["source"] == c["source"] and f["reduced"] == c["reduced"]
     assert set(c["reduced"]) <= set(f["changed"])
-    hf = {"qwen2": dict(layers="num_hidden_layers", d_model="hidden_size",
-                        heads="num_attention_heads",
-                        kv_heads="num_key_value_heads",
-                        d_ff="intermediate_size", vocab="vocab_size",
-                        rope_theta="rope_theta", norm_eps="rms_norm_eps",
-                        tied_embeddings="tie_word_embeddings"),
-          "chatglm": dict(layers="num_layers", d_model="hidden_size",
-                          heads="num_attention_heads",
-                          kv_heads="multi_query_group_num",
-                          head_dim="kv_channels", d_ff="ffn_hidden_size",
-                          vocab="padded_vocab_size",
-                          norm_eps="layernorm_epsilon",
-                          qkv_bias="add_qkv_bias",
-                          tied_embeddings="tie_word_embeddings")
-          }[f["model_type"]]
-    for ours, theirs in hf.items():
+    for ours, theirs in f["published_keys"].items():
         assert m[ours] == f[theirs], ours
-    assert m["head_dim"] * m["heads"] == m["d_model"]
+    spec.family(m["family"], base).check_published(f)
     assert m["dtype"] == f["torch_dtype"]
     # the program takes the group as it is
     cfg = program.model_config(f["name"], m)
     assert cfg.padded_vocab == m["vocab_padded"]
+
+
+@pytest.mark.parametrize("c", BENCH["configs"], ids=lambda c: c["name"])
+def test_model_group_follows_the_published_keys(c):
+    check_model_group(c)
 
 
 @pytest.mark.parametrize("m", BENCH["per_layer"], ids=lambda m: m["name"])

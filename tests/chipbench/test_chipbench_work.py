@@ -5,6 +5,7 @@ from pathlib import Path
 import pytest
 
 from chipbench import peaks, work
+from chipbench.families import dense_gqa
 
 CONFIGS = Path(__file__).resolve().parents[2] / "chipbench" / "configs"
 
@@ -27,7 +28,7 @@ def test_qwen2_decode_step_by_hand():
         + d * V * 2 + d * 4 + d * 2
     kv = 24 * 2 * 2 * 64 * 2 * 1001               # 12,288 B a token
     assert nbytes == weights + kv
-    assert work.kv_bytes_per_token(m) == 12_288
+    assert dense_gqa.kv_bytes_per_token(m) == 12_288
     # a single decode step is bound by bytes: about 1.2 ms at 819 GB/s
     pk = peaks.peak("TPU v5 lite")
     assert work.bound_s(flops, nbytes, pk) == pytest.approx(
@@ -37,7 +38,7 @@ def test_qwen2_decode_step_by_hand():
 def test_prefill_counts_causal_attention_once():
     m = model("chatglm3-6b")
     T = 1024
-    mm = 28 * work.layer_matmul_params(m)
+    mm = 28 * dense_gqa.layer_matmul_params(m)
     attn = 28 * 4 * 32 * 128 * T * (T + 1) / 2
     assert work.prefill(m, T) == 2 * mm * T + 2 * 4096 * 65024 + attn
 
