@@ -180,7 +180,9 @@ class ServingEngine:
         self.stats = EngineStats()
         self._next_tokens = np.zeros((slots,), np.int32)
         self._remaining = np.zeros((slots,), np.int32)
-        self._lens = np.zeros((slots,), np.int32)   # plan-only mirror
+        # each slot's cache length, counted on the host: the device's
+        # ``cache["len"]`` on the jitted path, the plan's on plan-only
+        self._lens = np.zeros((slots,), np.int32)
         self.trace: list[PlanRecord] = []
         self.n_records = 0          # records emitted (trace + sinks)
         self.n_finished = 0
@@ -321,6 +323,7 @@ class ServingEngine:
                 self.cache, cache1)
             self.cache["len"] = self.cache["len"].at[slot].set(
                 cache1["len"][0])
+        self._lens[slot] = len(req.prompt)      # what the prefill wrote
         with TraceAnnotation("engine.first_token", uid=req.uid):
             tok = int(self._fetch(
                 jnp.argmax(logits[0, :self.cfg.vocab_size])))
@@ -331,8 +334,7 @@ class ServingEngine:
         self.slot_req[slot] = req
         self.stats.tokens_out += 1
         if self._table is not None:
-            if not self._table.note_tokens(
-                    slot, int(self._fetch(self.cache["len"][slot]))):
+            if not self._table.note_tokens(slot, int(self._lens[slot])):
                 raise RuntimeError("shadow KV table out of pages")
             self._record(PlanRecord(
                 "prefill", self.stats.decode_steps, (slot,),
@@ -351,6 +353,7 @@ class ServingEngine:
         self.slot_req[slot] = None
         self.n_finished += 1
         self.cache["len"] = self.cache["len"].at[slot].set(0)
+        self._lens[slot] = 0
         if self._table is not None:
             self._table.free_seq(slot)
 
@@ -387,10 +390,11 @@ class ServingEngine:
             toks = jnp.asarray(self._next_tokens)
             self.cache, logits = self._decode(self.params, self.cache, toks)
         self.stats.decode_steps += 1
+        self._lens += 1         # the step moves every slot, free ones too
         if self._table is not None:
             for slot in active:
-                if not self._table.note_tokens(
-                        slot, int(self._fetch(self.cache["len"][slot]))):
+                if not self._table.note_tokens(slot,
+                                               int(self._lens[slot])):
                     raise RuntimeError("shadow KV table out of pages")
         with TraceAnnotation("engine.sample", step=idx):
             nxt = self._fetch(jnp.argmax(
@@ -405,9 +409,8 @@ class ServingEngine:
                 self._next_tokens[slot] = tok
                 self._remaining[slot] -= 1
                 hit_eos = self.eos is not None and tok == self.eos
-                if self._remaining[slot] <= 0 or hit_eos or int(
-                        self._fetch(self.cache["len"][slot])) >= \
-                        self.max_seq - 1:
+                if self._remaining[slot] <= 0 or hit_eos or \
+                        self._lens[slot] >= self.max_seq - 1:
                     self._retire(slot)
                     retired += 1
             span.set_metadata(retired=retired)

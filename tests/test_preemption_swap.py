@@ -21,6 +21,7 @@ The contract under test, layer by layer:
   TTFT and end-to-end time exactly.
 """
 import dataclasses
+import hashlib
 import math
 
 import numpy as np
@@ -265,6 +266,35 @@ class TestEnginePreemption:
                 if after <= 1:
                     found = True
         assert found
+
+    @pytest.mark.parametrize("policy,digest", [
+        ("lifo", "69fffc60d8428c2f"), ("longest", "dbfe9b6a4a0c2447")])
+    def test_plan_only_trace_and_lengths_are_pinned(self, policy, digest):
+        """A seeded overload run with preemption gives the records and
+        the per-step host lengths (``_lens``) pinned here, so changes to
+        the jitted path's bookkeeping leave the plan-only path as it
+        was."""
+        @dataclasses.dataclass
+        class LensLog:
+            seen: list
+
+            def on_step(self, eng, step):
+                self.seen.append(eng._lens.tolist())
+
+        eng = _engine(slots=3, max_seq=64, kv_pool_pages=13)
+        reqs = faults.adversarial_requests(60, seed=0, max_seq=64)
+        arr = faults.storm_arrivals(60, 400.0, seed=0)
+        log = LensLog([])
+        eng.run_open_loop(reqs, arr, prefill_chunk_tokens=8,
+                          est_step_s=1e-4, est_prefill_s_per_token=1e-5,
+                          preempt=policy, debug_invariants=True,
+                          faults=faults.FaultSchedule([
+                              faults.PoolShrinkFault(10, 3, 200), log]))
+        assert eng.stats.drained and eng.stats.preemptions > 0
+        recs = [(r.kind, r.step_idx, r.slots, r.uids, r.arrival_event,
+                 r.n_tokens) for r in eng.trace]
+        assert hashlib.sha256(repr((recs, log.seen, eng._lens.tolist()))
+                              .encode()).hexdigest()[:16] == digest
 
     def test_policy_validation(self):
         eng = _engine()

@@ -1,6 +1,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_reduced
 from repro.models.model import Model
@@ -61,3 +62,97 @@ def test_paged_cache_alloc_free_invariants():
     # exhaustion: can't allocate more pages than the pool holds
     assert cache.alloc_seq(1, prompt_len=32)
     assert not cache.alloc_seq(2, prompt_len=32 * 8)
+
+
+# ------------------------------------------- host mirror of cache lengths
+MIRROR_SLOTS, MIRROR_SEQ = 2, 32
+# (prompt length, max_new_tokens): request 0 retires by its count,
+# request 1 at max_seq - 1 (4 steps after a 27-token prompt), request 2
+# by EOS (chosen from its own tokens below); more requests than slots,
+# so slots are reused, and the last runs on alone beside a free slot
+MIRROR_REQS = ((6, 3), (27, 20), (10, 12), (6, 3), (10, 9))
+
+
+def _mirror_requests():
+    rng = np.random.default_rng(11)
+    return [Request(uid=i, prompt=rng.integers(1, 250, n).astype(np.int32),
+                    max_new_tokens=new)
+            for i, (n, new) in enumerate(MIRROR_REQS)]
+
+
+def _serve_reading_device_lengths(model, params, decode, prefill, eos):
+    """The engine's schedule written out plainly, reading each slot's
+    cache length from the device: FIFO admission into free slots, one
+    batched decode step, then per slot the tests count, EOS, length.
+    Returns {uid: (tokens, retiring step, reason)}."""
+    cache = model.init_cache(MIRROR_SLOTS, MIRROR_SEQ)
+    queue, held = _mirror_requests(), [None] * MIRROR_SLOTS
+    nxt = np.zeros(MIRROR_SLOTS, np.int32)
+    out, left, step = {}, {}, 0
+    while queue or any(r is not None for r in held):
+        for s in range(MIRROR_SLOTS):
+            if held[s] is None and queue:
+                r = held[s] = queue.pop(0)
+                one, logits = prefill(
+                    params, {"tokens": jnp.asarray(r.prompt[None])})
+                cache = jax.tree.map(
+                    lambda full, o: full.at[:, s].set(o[:, 0])
+                    if full.ndim >= 2 and full.shape[1] == MIRROR_SLOTS
+                    else full, cache, one)
+                cache["len"] = cache["len"].at[s].set(one["len"][0])
+                nxt[s] = int(jnp.argmax(logits[0, :model.cfg.vocab_size]))
+                out[r.uid], left[s] = [int(nxt[s])], r.max_new_tokens - 1
+        cache, logits = decode(params, cache, jnp.asarray(nxt))
+        step += 1
+        toks = np.asarray(jnp.argmax(logits[:, :model.cfg.vocab_size], -1))
+        for s, r in enumerate(held):
+            if r is None:
+                continue
+            nxt[s] = toks[s]
+            out[r.uid].append(int(toks[s]))
+            left[s] -= 1
+            why = ("count" if left[s] <= 0 else
+                   "eos" if toks[s] == eos else
+                   "length" if int(cache["len"][s]) >= MIRROR_SEQ - 1
+                   else None)
+            if why:
+                out[r.uid] = (out[r.uid], step, why)
+                held[s] = None
+                cache["len"] = cache["len"].at[s].set(0)
+    return out
+
+
+@pytest.mark.parametrize("arch", ["qwen2_0_5b", "qwen2_moe_a2_7b",
+                                  "rwkv6_7b", "zamba2_7b"])
+def test_host_lengths_match_the_device_and_retire_as_it_would(arch):
+    """The engine keeps every slot's cache length on the host: after
+    each step it equals the device's ``cache["len"]``, free slots
+    included, and each request retires at the step and with the tokens
+    of a loop that reads the lengths from the device."""
+    cfg = get_reduced(arch)
+    model = Model(cfg, remat="none")
+    params = model.init(jax.random.PRNGKey(0))
+    decode = jax.jit(model.decode_step)
+    prefill = jax.jit(lambda p, b: model.prefill(p, b, MIRROR_SEQ))
+    plain = _serve_reading_device_lengths(model, params, decode, prefill,
+                                          None)
+    # EOS: a later token of request 2 that requests 0 and 1 never emit
+    eos = next(t for t in plain[2][0][1:]
+               if t not in plain[0][0] + plain[1][0])
+    want = _serve_reading_device_lengths(model, params, decode, prefill,
+                                         eos)
+    assert [want[u][2] for u in range(3)] == ["count", "length", "eos"]
+
+    eng = ServingEngine(cfg, params, slots=MIRROR_SLOTS,
+                        max_seq=MIRROR_SEQ, eos_token=eos)
+    reqs = _mirror_requests()
+    for r in reqs:
+        eng.submit(r)
+    got = {}
+    while eng.step():
+        np.testing.assert_array_equal(eng._lens,
+                                      np.asarray(eng.cache["len"]))
+        got.update((r.uid, (r.output, eng.stats.decode_steps))
+                   for r in reqs if r.done_s is not None
+                   and r.uid not in got)
+    assert got == {u: (toks, step) for u, (toks, step, _) in want.items()}
