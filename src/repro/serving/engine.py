@@ -4,14 +4,15 @@ Slot-based continuous batching over the Model API: B decode slots run in
 a single jitted decode step (per-slot cache lengths — mixed-progress
 sequences in one batch); finished slots are recycled and newly admitted
 requests are prefetched (prefilled) into their slot between decode
-steps. This is the end-to-end driver the paper's inference setting
-dictates (serve batched requests, GEMMs streamed, host orchestrates).
+steps. ``serving.scheduler`` decides what to admit, prefill, decode and
+retire; this module runs it on the model and records it. This is the
+end-to-end serving loop the paper's inference setting dictates (serve
+batched requests, GEMMs streamed, host orchestrates).
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from collections import deque
 from typing import Optional
 
 import jax
@@ -21,6 +22,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models.model import Model
+from repro.serving.scheduler import PlanRecord, Scheduler
 
 
 @dataclasses.dataclass
@@ -36,25 +38,6 @@ class Request:
     # index into the engine's plan trace at submission time — the
     # simulated-arrival anchor for TTFT attribution (0 == trace start)
     arrival_event: int = 0
-
-
-@dataclasses.dataclass
-class PlanRecord:
-    """One priced event of a recorded serving trace: a prompt prefill
-    (one per admission, or one per chunk under chunked prefill) or a
-    batched multi-layer decode step, tagged with the engine step index
-    and the slot -> request-uid mapping so simulated time folds back
-    onto individual requests.  ``uids == (-1,)`` marks the shared
-    prefix-cache prefill, which belongs to no request.  ``swap_out`` /
-    ``swap_in`` records carry a preempted request's KV traffic to and
-    from host (``n_tokens`` = cached tokens at the swap point)."""
-    kind: str             # "prefill" | "decode" | "swap_out" | "swap_in"
-    step_idx: int                   # engine decode-step counter
-    slots: tuple                    # slot ids this plan covers
-    uids: tuple                     # request uid per slot
-    plan: object                    # core.plan.StreamPlan
-    arrival_event: int = 0          # prefill: requester's arrival index
-    n_tokens: int = 0               # prefill: tokens this chunk covers
 
 
 def arrival_times(kind: str, n: int, qps: float, seed: int = 0, *,
@@ -121,28 +104,16 @@ class EngineStats:
 
 
 class ServingEngine:
-    """``record_plans=True`` shadows the dense decode cache with a
-    driver-side ``PageTable`` (no device pools) and records a
-    request-centric plan trace (``trace``): one ``prefill_plan`` per
-    admission and one multi-layer GQA ``decode_step_plan`` per engine
-    step, each tagged with ``(step_idx, slot -> uid)`` — page ids and
-    valid lengths track the REAL batch composition (admissions,
-    retirements, page churn) over the run, so one batched accesys
-    replay prices the whole trace and folds simulated time back onto
-    individual requests (``serving.sim_report``).
-
-    Admission against the shadow pool is CONSERVATIVE: a request is
-    admitted only if the free list can hold its maximum length
-    (prompt + max_new_tokens, capped at ``max_seq``) on top of the
-    worst-case remaining growth of every already-admitted request —
-    so decode-time page growth can never fail and the engine never
-    crashes mid-run on pool pressure.  Otherwise the request is
-    DEFERRED at the head of the queue (FIFO order preserved) until
-    retirements drain enough pages.  A request whose maximum length
-    cannot fit even an empty pool raises ``ValueError`` at admission
-    time (a configuration error deferral would turn into a livelock).
-    ``kv_pool_pages`` caps the pool (default: every slot can grow to
-    ``max_seq``, so only explicit caps ever defer)."""
+    """Drives one ``serving.scheduler.Scheduler`` (``sched``) and hands
+    what it decides to each consumer it has: the jitted model, and with
+    ``record_plans=True`` a host-side ``PageTable`` (no device pools)
+    whose plan trace (``trace``) holds one ``prefill_plan`` per prefill
+    span and one multi-layer GQA ``decode_step_plan`` per engine step,
+    each tagged with ``(step_idx, slot -> uid)``, so one batched accesys
+    replay prices the whole run and folds simulated time back onto each
+    request (``serving.sim_report``).  ``kv_pool_pages`` caps the pool
+    (default: every slot can grow to ``max_seq``, so only explicit caps
+    ever defer an admission)."""
 
     def __init__(self, cfg: ModelConfig, params=None, *, slots: int = 4,
                  max_seq: int = 256, eos_token: Optional[int] = None,
@@ -151,64 +122,39 @@ class ServingEngine:
                  kv_pool_pages: Optional[int] = None,
                  plan_only: bool = False, prefix_tokens: int = 0,
                  prefix_caching: bool = False, templated: bool = True):
-        """``plan_only=True`` skips model/cache/jit construction
-        entirely (``params`` unused) and drives the shadow PageTable
-        alone — the open-loop capacity-planning mode, where generated
-        token VALUES never matter and only the plan trace does.
-        ``prefix_tokens`` prepends a shared system prompt to every
-        request; with ``prefix_caching=True`` its pages are interned
-        once per trace (``reserve_prefix``) and every request maps
-        them read-only, otherwise each request re-prefills them.
-        ``templated`` (default) emits template-instanced plan records
-        — each decode/prefill/swap record is a compiled-skeleton
-        page-id relabel instead of a fresh event graph, pricing
-        bitwise-identically (``templated=False`` restores event-built
-        records; ``.events`` on a templated record rebuilds them on
-        demand)."""
+        """``plan_only=True`` builds no model, cache or jit (``params``
+        unused) and drives the shadow PageTable alone — the open-loop
+        capacity-planning mode, where only the plan trace matters.
+        ``prefix_tokens`` (plan-only) prepends a shared system prompt to
+        every request; with ``prefix_caching=True`` its pages are
+        interned once per trace and every request maps them read-only,
+        otherwise each request re-prefills them.  ``templated`` (default)
+        emits template-instanced plan records that price
+        bitwise-identically to event-built ones (``.events`` on a
+        templated record rebuilds them on demand)."""
+        if prefix_tokens and not plan_only:
+            raise ValueError("prefix_tokens needs plan_only=True: the "
+                             "model prefills no shared prefix")
         self.cfg = cfg
-        self.plan_only = plan_only
-        record_plans = record_plans or plan_only
         self.model = None if plan_only else Model(cfg, remat="none")
         self.params = params
         self.slots = slots
         self.max_seq = max_seq
-        self.eos = eos_token
         self.cache = None if plan_only else \
             self.model.init_cache(slots, max_seq)
-        self.slot_req: list[Optional[Request]] = [None] * slots
-        self.queue: deque[Request] = deque()
         self.stats = EngineStats()
         self._next_tokens = np.zeros((slots,), np.int32)
-        self._remaining = np.zeros((slots,), np.int32)
-        # each slot's cache length, counted on the host: the device's
-        # ``cache["len"]`` on the jitted path, the plan's on plan-only
-        self._lens = np.zeros((slots,), np.int32)
         self.trace: list[PlanRecord] = []
         self.n_records = 0          # records emitted (trace + sinks)
-        self.n_finished = 0
-        self.deferred_admissions = 0
         self.sim_t = 0.0            # open-loop simulated clock
         self._sink: Optional[list] = None
-        self._prefilling: dict = {}  # slot -> [req, done, total]
-        # ---- preemption / swap state (open-loop path)
-        self._preempt_policy = "none"
-        self._stall_budget_s = 0.0
-        self._debug_invariants = False
-        self._defer_since: Optional[float] = None  # head's wait start
-        self._swapped: dict = {}    # uid -> (n_pages, tokens, remaining,
-        #                             prefill_total | None)
-        self._progress: dict = {}   # slot -> tokens since (re)admission
-        self._admit_seq: dict = {}  # slot -> admission order counter
-        self._admit_counter = 0
-        self._prefix_tokens = int(prefix_tokens)
-        self._prefix_pages: Optional[np.ndarray] = None
         self._prefix_recorded = False
-        self._table = None
-        if record_plans:
+        table = None
+        if record_plans or plan_only:
             from repro.serving.kv_cache import (PagedCacheConfig,
                                                 PageTable)
             pages_per_seq = -(-max_seq // kv_page_tokens)
-            self._table = PageTable(
+            table = PageTable(
                 PagedCacheConfig(
                     n_pages=kv_pool_pages or slots * pages_per_seq,
                     page_tokens=kv_page_tokens,
@@ -217,22 +163,24 @@ class ServingEngine:
                     max_pages_per_seq=pages_per_seq,
                     dtype=kv_dtype),
                 max_seqs=slots, templated=templated)
-        if self._prefix_tokens:
-            if self._table is None:
-                raise ValueError("prefix_tokens needs record_plans")
-            if self._prefix_tokens % kv_page_tokens:
-                raise ValueError(
-                    f"prefix_tokens={prefix_tokens} must be a multiple "
-                    f"of kv_page_tokens={kv_page_tokens} (chunked "
-                    "prefill spans are page-aligned)")
-            if prefix_caching:
-                self._prefix_pages = self._table.reserve_prefix(
-                    self._prefix_tokens // kv_page_tokens)
+        self.sched = Scheduler(
+            cfg, self.stats, self._record, slots=slots, max_seq=max_seq,
+            eos=eos_token, table=table, prefix_tokens=prefix_tokens,
+            prefix_caching=prefix_caching,
+            clock=(lambda: self.sim_t) if plan_only else
+            time.perf_counter)
 
         if not plan_only:
             self._decode = jax.jit(self.model.decode_step)
             self._prefill1 = jax.jit(
                 lambda p, b: self.model.prefill(p, b, max_seq))
+
+    # the scheduler's state that callers read
+    queue = property(lambda self: self.sched.queue)
+    slot_req = property(lambda self: self.sched.slot_req)
+    n_finished = property(lambda self: self.sched.n_finished)
+    deferred_admissions = property(
+        lambda self: self.sched.deferred_admissions)
 
     @property
     def step_plans(self) -> list:
@@ -241,45 +189,17 @@ class ServingEngine:
         return [r.plan for r in self.trace if r.kind == "decode"]
 
     # ------------------------------------------------------------- API
-    def _record(self, rec: PlanRecord) -> int:
+    def _record(self, rec: PlanRecord):
         """Append a trace record (to the streaming sink when one is
-        installed) and return its global index — the ``arrival_event``
+        installed); ``n_records`` counts them, the ``arrival_event``
         coordinate space."""
-        idx = self.n_records
         self.n_records += 1
         (self.trace if self._sink is None else self._sink).append(rec)
-        return idx
 
     def submit(self, req: Request):
         req.submitted_s = time.perf_counter()
         req.arrival_event = self.n_records
         self.queue.append(req)
-
-    def _max_pages(self, req: Request) -> int:
-        """Worst-case pages ``req`` can ever hold (shared prefix pages
-        included): its final cache length is min(prefix + prompt +
-        max_new_tokens - 1, max_seq - 1), padded to max_seq here for
-        safety."""
-        max_len = min(self._prefix_tokens + len(req.prompt)
-                      + req.max_new_tokens, self.max_seq)
-        return -(-max_len // self._table.cfg.page_tokens)
-
-    def _can_admit(self, req: Request) -> bool:
-        t = self._table
-        need = self._max_pages(req)
-        if need > min(t.cfg.n_pages, t.cfg.max_pages_per_seq):
-            raise ValueError(
-                f"request uid={req.uid} needs {need} KV pages at its "
-                f"max length but the pool can never hold that "
-                f"(n_pages={t.cfg.n_pages}, "
-                f"max_pages_per_seq={t.cfg.max_pages_per_seq})")
-        if self._prefix_pages is not None:
-            need -= len(self._prefix_pages)   # shared pages are mapped
-        # pages admitted slots may still claim while decoding
-        growth = sum(self._max_pages(r) - int(t.held[s])
-                     for s, r in enumerate(self.slot_req)
-                     if r is not None)
-        return len(t._free) >= need + growth
 
     def _fetch(self, x, dtype=None) -> np.ndarray:
         """Device array ``x`` on the host. Every device->host read of the
@@ -288,77 +208,39 @@ class ServingEngine:
         self.stats.host_syncs += 1
         return np.asarray(x, dtype)
 
-    def _admit(self):
-        for slot in range(self.slots):
-            if self.slot_req[slot] is not None or not self.queue:
-                continue
-            req = self.queue[0]
-            if self._table is not None:
-                if not self._can_admit(req):
-                    # defer admission — the request stays queued until
-                    # retirements free enough pages for its max length
-                    self.deferred_admissions += 1
-                    return
-                if not self._table.alloc_seq(slot, len(req.prompt)):
-                    raise RuntimeError(       # _can_admit guarantees it
-                        "shadow KV table out of pages at admission")
-            with TraceAnnotation("engine.admit", uid=req.uid, slot=slot,
-                                 prompt_len=len(req.prompt)):
-                self.queue.popleft()
-                req.admitted_s = time.perf_counter()
-                self._admit_one(req, slot)
-
-    def _admit_one(self, req: Request, slot: int):
-        with TraceAnnotation("engine.prefill", uid=req.uid):
-            cache1, logits = self._prefill1(
-                self.params, {"tokens": jnp.asarray(req.prompt[None])})
-        self.stats.prefills += 1
-        with TraceAnnotation("engine.splice", uid=req.uid, slot=slot):
-            # splice the single-seq cache into this slot
-            self.cache = jax.tree.map(
-                lambda full, one: (
-                    full.at[:, slot].set(one[:, 0])
-                    if full.ndim >= 2 and full.shape[1] == self.slots
-                    else full),
-                self.cache, cache1)
-            self.cache["len"] = self.cache["len"].at[slot].set(
-                cache1["len"][0])
-        self._lens[slot] = len(req.prompt)      # what the prefill wrote
-        with TraceAnnotation("engine.first_token", uid=req.uid):
-            tok = int(self._fetch(
-                jnp.argmax(logits[0, :self.cfg.vocab_size])))
-        req.first_token_s = time.perf_counter()
-        req.output.append(tok)
+    def _prefill(self, slot: int, req: Request) -> int:
+        """Prefill ``req``'s whole prompt on the device, splice its cache
+        into ``slot`` and return its first token."""
+        with TraceAnnotation("engine.admit", uid=req.uid, slot=slot,
+                             prompt_len=len(req.prompt)):
+            req.admitted_s = time.perf_counter()
+            with TraceAnnotation("engine.prefill", uid=req.uid):
+                cache1, logits = self._prefill1(
+                    self.params, {"tokens": jnp.asarray(req.prompt[None])})
+            with TraceAnnotation("engine.splice", uid=req.uid, slot=slot):
+                # splice the single-seq cache into this slot
+                self.cache = jax.tree.map(
+                    lambda full, one: (
+                        full.at[:, slot].set(one[:, 0])
+                        if full.ndim >= 2 and full.shape[1] == self.slots
+                        else full),
+                    self.cache, cache1)
+                self.cache["len"] = self.cache["len"].at[slot].set(
+                    cache1["len"][0])
+            with TraceAnnotation("engine.first_token", uid=req.uid):
+                tok = int(self._fetch(
+                    jnp.argmax(logits[0, :self.cfg.vocab_size])))
+            req.first_token_s = time.perf_counter()
         self._next_tokens[slot] = tok
-        self._remaining[slot] = req.max_new_tokens - 1
-        self.slot_req[slot] = req
-        self.stats.tokens_out += 1
-        if self._table is not None:
-            if not self._table.note_tokens(slot, int(self._lens[slot])):
-                raise RuntimeError("shadow KV table out of pages")
-            self._record(PlanRecord(
-                "prefill", self.stats.decode_steps, (slot,),
-                (req.uid,),
-                self._table.prefill_plan(
-                    slot, len(req.prompt),
-                    n_q_heads=self.cfg.n_heads,
-                    d_model=self.cfg.d_model, d_ff=self.cfg.d_ff,
-                    n_layers=self.cfg.n_layers),
-                arrival_event=req.arrival_event,
-                n_tokens=len(req.prompt)))
+        return tok
 
-    def _retire(self, slot: int):
-        req = self.slot_req[slot]
-        req.done_s = time.perf_counter()
-        self.slot_req[slot] = None
-        self.n_finished += 1
+    def _free(self, slot: int):
+        """A retired slot's device cache length back to 0."""
         self.cache["len"] = self.cache["len"].at[slot].set(0)
-        self._lens[slot] = 0
-        if self._table is not None:
-            self._table.free_seq(slot)
 
-    def step(self):
-        """One engine iteration: admit + one batched decode step.
+    def step(self) -> bool:
+        """One engine iteration: admit, prefill, one batched decode step.
+        False when there was nothing to do.
 
         Host spans (``jax.profiler.TraceAnnotation``, recorded only
         while a profiler trace runs): ``engine.step`` holds the
@@ -370,64 +252,56 @@ class ServingEngine:
         idx = self.stats.decode_steps
         with TraceAnnotation("engine.step", step=idx,
                              active=self.slots - self.slot_req.count(None)):
-            return self._step(idx)
+            spans, decoded = self._step(idx)
+        return decoded or bool(spans)
 
-    def _step(self, idx: int) -> bool:
-        self._admit()
-        active = [i for i, r in enumerate(self.slot_req) if r is not None]
+    def _step(self, idx: int, chunk: Optional[int] = None) -> tuple:
+        """The one step body of every run: the scheduler admits,
+        advances each prefill by one span (``chunk`` tokens at most;
+        the whole prompt without), and books one decode step over the
+        batch; the model, where there is one, runs each of them.
+        Returns the token count of each prefill span and whether a
+        decode step ran."""
+        s = self.sched
+        s.admit()
+        spans = []
+        for slot in sorted(s.prefilling):
+            req, done, end = s.next_span(slot, chunk)
+            tok = None if self.model is None else self._prefill(slot, req)
+            if s.prefilled(slot, end, tok) and self.model is not None:
+                self._free(slot)
+            spans.append(end - done)
+        active = s.batch()
         if not active:
-            return False
-        if self._table is not None:
-            # the step streams each active slot's currently-resident KV
-            # pages; the new token's KV lands before the next step
-            self._record(PlanRecord(
-                "decode", idx, tuple(active),
-                tuple(self.slot_req[s].uid for s in active),
-                self._table.decode_step_plan(
-                    active, n_q_heads=self.cfg.n_heads,
-                    n_layers=self.cfg.n_layers)))
-        with TraceAnnotation("engine.decode", step=idx):
-            toks = jnp.asarray(self._next_tokens)
-            self.cache, logits = self._decode(self.params, self.cache, toks)
-        self.stats.decode_steps += 1
-        self._lens += 1         # the step moves every slot, free ones too
-        if self._table is not None:
-            for slot in active:
-                if not self._table.note_tokens(slot,
-                                               int(self._lens[slot])):
-                    raise RuntimeError("shadow KV table out of pages")
-        with TraceAnnotation("engine.sample", step=idx):
-            nxt = self._fetch(jnp.argmax(
-                logits[:, :self.cfg.vocab_size], axis=-1), np.int32)
+            return spans, False
+        nxt = None
+        if self.model is not None:
+            with TraceAnnotation("engine.decode", step=idx):
+                toks = jnp.asarray(self._next_tokens)
+                self.cache, logits = self._decode(self.params, self.cache,
+                                                  toks)
+            with TraceAnnotation("engine.sample", step=idx):
+                nxt = self._fetch(jnp.argmax(
+                    logits[:, :self.cfg.vocab_size], axis=-1), np.int32)
+            self._next_tokens[active] = nxt[active]
         with TraceAnnotation("engine.retire", step=idx) as span:
-            retired = 0
-            for slot in active:
-                req = self.slot_req[slot]
-                tok = int(nxt[slot])
-                req.output.append(tok)
-                self.stats.tokens_out += 1
-                self._next_tokens[slot] = tok
-                self._remaining[slot] -= 1
-                hit_eos = self.eos is not None and tok == self.eos
-                if self._remaining[slot] <= 0 or hit_eos or \
-                        self._lens[slot] >= self.max_seq - 1:
-                    self._retire(slot)
-                    retired += 1
-            span.set_metadata(retired=retired)
-        return True
+            retired = s.decoded(active, nxt)
+            if self.model is not None:
+                for slot in retired:
+                    self._free(slot)
+            span.set_metadata(retired=len(retired))
+        return spans, True
 
     def run_until_drained(self, max_steps: int = 10_000) -> EngineStats:
         t0 = time.perf_counter()
         steps = 0
-        while (self.queue or any(r is not None for r in self.slot_req)) \
-                and steps < max_steps:
+        while self.sched.busy and steps < max_steps:
             self.step()
             steps += 1
         self.stats.wall_s = time.perf_counter() - t0
         # hitting max_steps with work left is a TRUNCATED run — flag
         # it so partial stats can't pass for a drained queue
-        self.stats.drained = not self.queue and \
-            all(r is None for r in self.slot_req)
+        self.stats.drained = not self.sched.busy
         return self.stats
 
     # ------------------------------------------ open-loop (plan-only)
@@ -438,215 +312,6 @@ class ServingEngine:
         live = [r.uid for r in self.slot_req if r is not None]
         live += [r.uid for r in self.queue]
         return tuple(live)
-
-    def _pick_victim(self, exclude: Optional[int] = None
-                     ) -> Optional[int]:
-        """Choose a running slot to preempt, or None.  Only slots that
-        have produced at least one token since (re)admission are
-        eligible — preempting zero-progress work can livelock two
-        large requests into evicting each other forever, while
-        requiring progress guarantees every preemption cycle advances
-        someone.  ``lifo``: most recently admitted (vLLM's default —
-        the newest request has the least sunk cost); ``longest``: most
-        own pages held (frees the most memory per eviction)."""
-        cands = [s for s, r in enumerate(self.slot_req)
-                 if r is not None and s != exclude
-                 and self._progress.get(s, 0) > 0]
-        if not cands:
-            return None
-        if self._preempt_policy == "lifo":
-            return max(cands, key=lambda s: self._admit_seq[s])
-        # "longest": frees the most device pages
-        t = self._table
-        return max(cands, key=lambda s: (int(t.held[s])
-                                         - int(t.shared[s]),
-                                         self._admit_seq[s]))
-
-    def _preempt(self, slot: int):
-        """Evict ``slot``: record the page-aligned swap-out of its
-        written KV (``PageTable.swap_out`` frees the device pages),
-        stash its exact progress for resume, and re-queue it directly
-        BEHIND the queue head — the head's admission is the point of
-        the eviction, and the victim resumes right after it."""
-        req = self.slot_req[slot]
-        pf = self._prefilling.pop(slot, None)
-        if pf is not None:
-            tokens, remaining, total = pf[1], -1, pf[2]
-        else:
-            tokens = int(self._lens[slot])
-            remaining, total = int(self._remaining[slot]), None
-        plan, n_swap = self._table.swap_out(
-            slot, tokens, req.uid, n_layers=self.cfg.n_layers)
-        if plan is not None:
-            self._record(PlanRecord(
-                "swap_out", self.stats.decode_steps, (slot,),
-                (req.uid,), plan, arrival_event=req.arrival_event,
-                n_tokens=tokens))
-        self._swapped[req.uid] = (n_swap, tokens, remaining, total)
-        self.slot_req[slot] = None
-        self._lens[slot] = 0
-        self._progress.pop(slot, None)
-        self.stats.preemptions += 1
-        self.stats.swapped_pages += n_swap
-        if self.queue:
-            head = self.queue.popleft()
-            self.queue.appendleft(req)
-            self.queue.appendleft(head)
-        else:
-            self.queue.appendleft(req)
-
-    def _resume_or_start(self, slot: int, req: Request):
-        """Bind ``req`` to ``slot``: allocate its device pages and
-        either enter the chunked-prefill state machine (fresh request,
-        or one preempted mid-prefill — it continues at the chunk
-        boundary it stopped on) or rejoin the decode batch (preempted
-        while decoding), recording the swap-in DMA first."""
-        swap = self._swapped.pop(req.uid, None)
-        full = self._prefix_tokens + len(req.prompt)
-        alloc_tokens = full if swap is None else max(full, swap[1])
-        if not self._table.alloc_seq(slot, alloc_tokens,
-                                     prefix=self._prefix_pages):
-            raise RuntimeError(       # _can_admit guarantees it
-                "shadow KV table out of pages at admission")
-        self.slot_req[slot] = req
-        self._progress[slot] = 0
-        self._admit_seq[slot] = self._admit_counter
-        self._admit_counter += 1
-        if swap is None:
-            done = self._prefix_tokens \
-                if self._prefix_pages is not None else 0
-            self._prefilling[slot] = [req, done, full]
-            return
-        n_swap, tokens, remaining, total = swap
-        if n_swap:
-            self._record(PlanRecord(
-                "swap_in", self.stats.decode_steps, (slot,),
-                (req.uid,),
-                self._table.swap_in_plan(n_swap, req.uid,
-                                         n_layers=self.cfg.n_layers),
-                arrival_event=req.arrival_event, n_tokens=tokens))
-        if total is not None:            # was mid-prefill: continue it
-            self._prefilling[slot] = [req, tokens, total]
-        else:                            # was decoding: rejoin batch
-            self._lens[slot] = tokens
-            if not self._table.note_tokens(slot, tokens):
-                raise RuntimeError(   # alloc_seq covered these pages
-                    "swap-in lost pages the allocation reserved")
-            self._remaining[slot] = remaining
-
-    def _admit_open(self):
-        """Open-loop admission: same conservative capacity check as
-        ``_admit``, but the admitted request enters the chunked-prefill
-        state machine instead of being prefilled whole — long prompts
-        cost several engine steps, not one monolithic stall.
-
-        With a preemption policy armed, a head deferred longer than
-        the stall budget evicts victims (``_pick_victim``) until its
-        conservative reservation fits — head-of-line blocking degrades
-        into swap thrash instead of unbounded queueing."""
-        for slot in range(self.slots):
-            if self.slot_req[slot] is not None or not self.queue:
-                continue
-            if not self._can_admit(self.queue[0]):
-                if self._defer_since is None:
-                    self._defer_since = self.sim_t
-                if self._preempt_policy != "none" and \
-                        self.sim_t - self._defer_since >= \
-                        self._stall_budget_s:
-                    while not self._can_admit(self.queue[0]):
-                        victim = self._pick_victim()
-                        if victim is None:
-                            break
-                        self._preempt(victim)
-                if not self._can_admit(self.queue[0]):
-                    self.deferred_admissions += 1
-                    return
-            self._defer_since = None
-            req = self.queue.popleft()
-            self._resume_or_start(slot, req)
-
-    def _retire_open(self, slot: int):
-        req = self.slot_req[slot]
-        req.done_s = self.sim_t
-        self.slot_req[slot] = None
-        self.n_finished += 1
-        self._lens[slot] = 0
-        self._progress.pop(slot, None)
-        self._table.free_seq(slot)
-
-    def _prefill_chunk_open(self, slot: int, chunk: int,
-                            est_prefill_s_per_token: float) -> float:
-        """Advance one slot's chunked prefill by one page-aligned span
-        and record its plan; on the last chunk the slot joins the
-        decode batch (its ``first token`` is the prefill's)."""
-        req, done, total = self._prefilling[slot]
-        end = total if total - done <= chunk else done + chunk
-        self._record(PlanRecord(
-            "prefill", self.stats.decode_steps, (slot,), (req.uid,),
-            self._table.prefill_plan(
-                slot, total, span=(done, end),
-                n_q_heads=self.cfg.n_heads, d_model=self.cfg.d_model,
-                d_ff=self.cfg.d_ff, n_layers=self.cfg.n_layers),
-            arrival_event=req.arrival_event, n_tokens=end - done))
-        self.stats.prefills += 1
-        self._progress[slot] = self._progress.get(slot, 0) + end - done
-        if end == total:
-            del self._prefilling[slot]
-            self._lens[slot] = total
-            if not self._table.note_tokens(slot, total):
-                raise RuntimeError("shadow KV table out of pages")
-            self._remaining[slot] = req.max_new_tokens - 1
-            self.stats.tokens_out += 1
-            if self._remaining[slot] <= 0 or total >= self.max_seq - 1:
-                self._retire_open(slot)       # prefill-only request
-        else:
-            self._prefilling[slot][1] = end
-        return est_prefill_s_per_token * (end - done)
-
-    def _step_open(self, chunk: int, est_step_s: float,
-                   est_prefill_s_per_token: float) -> float:
-        """One open-loop engine iteration: advance every in-flight
-        chunked prefill by one span, then one batched decode step over
-        the slots not still prefilling.  Returns the simulated time
-        this step consumed (the admission clock — reported latencies
-        come from the accesys replay, not from these estimates)."""
-        dt = 0.0
-        for slot in sorted(self._prefilling):
-            dt += self._prefill_chunk_open(slot, chunk,
-                                           est_prefill_s_per_token)
-        active = [s for s, r in enumerate(self.slot_req)
-                  if r is not None and s not in self._prefilling]
-        if active:
-            self._record(PlanRecord(
-                "decode", self.stats.decode_steps, tuple(active),
-                tuple(self.slot_req[s].uid for s in active),
-                self._table.decode_step_plan(
-                    active, n_q_heads=self.cfg.n_heads,
-                    n_layers=self.cfg.n_layers)))
-            self.stats.decode_steps += 1
-            dt += est_step_s
-            for slot in active:
-                self._lens[slot] += 1
-                self._progress[slot] = self._progress.get(slot, 0) + 1
-                self.stats.tokens_out += 1
-                self._remaining[slot] -= 1
-                grew = self._table.note_tokens(slot,
-                                               int(self._lens[slot]))
-                if self._remaining[slot] <= 0 or \
-                        int(self._lens[slot]) >= self.max_seq - 1:
-                    self._retire_open(slot)
-                elif not grew:
-                    # mid-decode page growth failed — only reachable
-                    # when the pool shrank under us (fault injection
-                    # seizing pages breaks the conservative admission
-                    # reservation).  Degrade gracefully: swap this
-                    # slot out and resume it when pages return,
-                    # instead of crashing the run.
-                    if self._preempt_policy == "none":
-                        raise RuntimeError(
-                            "shadow KV table out of pages")
-                    self._preempt(slot)
-        return dt
 
     def open_loop_records(self, requests, arrival_s, *,
                           est_step_s: float = 1e-3,
@@ -665,7 +330,9 @@ class ServingEngine:
         memory.  Plan-only: token values are never computed; the
         ``est_*`` rates only advance the simulated admission clock
         (calibrate them from a small priced probe trace — reported
-        TTFT/TPOT always come from the replay itself).
+        TTFT/TPOT always come from the replay itself).  Prompts are
+        prefilled in page-aligned spans of ``prefill_chunk_tokens``, so
+        a long prompt costs several engine steps, not one stall.
 
         ``preempt`` arms graceful degradation under memory pressure:
         when the queue head has been deferred for more than
@@ -679,8 +346,8 @@ class ServingEngine:
         every step and at drain.
 
         Deterministic: same requests + arrivals => identical records.
-        Use ``run_open_loop`` to retain the trace instead."""
-        if not self.plan_only or self._table is None:
+        ``run_open_loop`` retains the trace instead."""
+        if self.model is not None:
             raise ValueError(
                 "open_loop_records() needs plan_only=True (the jitted "
                 "model path is closed-loop only)")
@@ -691,13 +358,15 @@ class ServingEngine:
         if stall_budget_s < 0:
             raise ValueError(
                 f"stall_budget_s must be >= 0: {stall_budget_s}")
-        self._preempt_policy = preempt
-        self._stall_budget_s = float(stall_budget_s)
-        self._debug_invariants = bool(debug_invariants)
-        if prefill_chunk_tokens % self._table.cfg.page_tokens:
+        s = self.sched
+        s.preempt_policy = preempt
+        s.stall_budget_s = float(stall_budget_s)
+        if prefill_chunk_tokens % s.table.cfg.page_tokens:
             raise ValueError(
                 f"prefill_chunk_tokens={prefill_chunk_tokens} must be "
-                f"page-aligned ({self._table.cfg.page_tokens} tokens)")
+                f"page-aligned ({s.table.cfg.page_tokens} tokens)")
+        if debug_invariants:
+            from repro.serving import invariants
         reqs = list(requests)
         arr = np.asarray(arrival_s, float)
         if len(reqs) != arr.size:
@@ -706,25 +375,22 @@ class ServingEngine:
         buf: list = []
         self._sink = buf
         try:
-            if self._prefix_pages is not None and \
-                    not self._prefix_recorded:
+            if s.prefix_pages is not None and not self._prefix_recorded:
                 self._prefix_recorded = True
                 self._record(PlanRecord(
                     "prefill", 0, (), (-1,),
-                    self._table.shared_prefill_plan(
-                        self._prefix_pages, self._prefix_tokens,
+                    s.table.shared_prefill_plan(
+                        s.prefix_pages, s.prefix_tokens,
                         n_q_heads=self.cfg.n_heads,
                         d_model=self.cfg.d_model, d_ff=self.cfg.d_ff,
                         n_layers=self.cfg.n_layers),
-                    n_tokens=self._prefix_tokens))
+                    n_tokens=s.prefix_tokens))
             i = 0
             steps = 0
-            while i < len(reqs) or self.queue or \
-                    any(r is not None for r in self.slot_req):
-                if steps >= max_steps:
+            while steps < max_steps:
+                busy = s.busy
+                if not busy and i >= len(reqs):
                     break
-                busy = self.queue or \
-                    any(r is not None for r in self.slot_req)
                 if not busy and arr[i] > self.sim_t:
                     self.sim_t = float(arr[i])    # idle: jump ahead
                 while i < len(reqs) and arr[i] <= self.sim_t:
@@ -734,9 +400,10 @@ class ServingEngine:
                     i += 1
                 if faults is not None:
                     faults.on_step(self, steps)
-                self._admit_open()
-                dt = self._step_open(prefill_chunk_tokens, est_step_s,
-                                     est_prefill_s_per_token)
+                spans, decoded = self._step(self.stats.decode_steps,
+                                            prefill_chunk_tokens)
+                dt = sum((est_prefill_s_per_token * n for n in spans),
+                         0.0) + (est_step_s if decoded else 0.0)
                 self.sim_t += dt
                 if dt == 0.0 and self.queue and i < len(reqs) and \
                         all(r is None for r in self.slot_req):
@@ -745,17 +412,14 @@ class ServingEngine:
                     # jump to the next arrival so the queue keeps
                     # growing instead of the loop spinning in place
                     self.sim_t = max(self.sim_t, float(arr[i]))
-                if self._debug_invariants:
-                    from repro.serving import invariants
-                    invariants.check_step(self)
+                if debug_invariants:
+                    invariants.check_step(s)
                 steps += 1
                 yield from buf
                 buf.clear()
-            self.stats.drained = i >= len(reqs) and not self.queue \
-                and all(r is None for r in self.slot_req)
-            if self._debug_invariants and self.stats.drained:
-                from repro.serving import invariants
-                invariants.check_drained(self)
+            self.stats.drained = i >= len(reqs) and not s.busy
+            if debug_invariants and self.stats.drained:
+                invariants.check_drained(s)
         finally:
             self._sink = None
 

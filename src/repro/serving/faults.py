@@ -95,10 +95,10 @@ class PoolShrinkFault:
 
     def on_step(self, eng, step: int) -> None:
         if step == self.at_step and not self.seized:
-            self.seized = eng._table.seize_pages(self.n_pages)
+            self.seized = eng.sched.table.seize_pages(self.n_pages)
         if self.restore_step is not None and step >= self.restore_step \
                 and self.seized and not self.restored:
-            eng._table.restore_pages()
+            eng.sched.table.restore_pages()
             self.restored = True
 
 
@@ -163,7 +163,7 @@ def main(argv=None) -> int:
             if not eng.stats.drained:
                 print(f"FAIL seed={seed} {policy}: not drained")
                 return 1
-            invariants.check_drained(eng)
+            invariants.check_drained(eng.sched)
             invariants.check_trace_conservation(
                 eng.trace, reqs, max_seq=eng.max_seq)
             s = eng.stats

@@ -34,51 +34,54 @@ def _fail(msg: str):
     raise InvariantViolation(msg)
 
 
-def check_step(eng) -> None:
-    """Per-iteration engine coherence + pool accounting."""
-    t = eng._table
-    try:
-        t.validate()
-    except AssertionError as e:
-        _fail(f"pool accounting: {e}")
-    occupied = {s for s, r in enumerate(eng.slot_req) if r is not None}
-    if not set(eng._prefilling) <= occupied:
-        _fail(f"prefilling slots {sorted(eng._prefilling)} not a "
+def check_step(sched) -> None:
+    """Per-iteration coherence of a ``serving.scheduler.Scheduler`` and,
+    when it has a page table, its pool accounting."""
+    t = sched.table
+    if t is not None:
+        try:
+            t.validate()
+        except AssertionError as e:
+            _fail(f"pool accounting: {e}")
+    occupied = {s for s, r in enumerate(sched.slot_req) if r is not None}
+    if not set(sched.prefilling) <= occupied:
+        _fail(f"prefilling slots {sorted(sched.prefilling)} not a "
               f"subset of occupied {sorted(occupied)}")
-    running = {eng.slot_req[s].uid for s in occupied}
-    queued = [r.uid for r in eng.queue]
+    running = {sched.slot_req[s].uid for s in occupied}
+    queued = [r.uid for r in sched.queue]
     if len(queued) != len(set(queued)):
         _fail(f"duplicate uids in queue: {queued}")
     both = running & set(queued)
     if both:
         _fail(f"uids both running and queued: {sorted(both)}")
-    swapped_running = set(eng._swapped) & running
+    swapped_running = set(sched.swapped) & running
     if swapped_running:
         _fail(f"uids running with live swap state: "
               f"{sorted(swapped_running)}")
     for s in occupied:
-        if s not in eng._prefilling and int(eng._lens[s]) < 1:
+        if s not in sched.prefilling and int(sched.lens[s]) < 1:
             _fail(f"decoding slot {s} has no cached tokens")
 
 
-def check_drained(eng) -> None:
+def check_drained(sched) -> None:
     """Nothing survives a drained run but the permanent reservations."""
-    if eng.queue or any(r is not None for r in eng.slot_req):
-        _fail("check_drained on an engine with live work")
-    t = eng._table
-    expect = len(t._prefix) + len(t._seized)
-    if t.pages_in_use != expect:
-        _fail(f"post-drain pages_in_use={t.pages_in_use}, expected "
-              f"{expect} (prefix {len(t._prefix)} + seized "
-              f"{len(t._seized)}) — leaked "
-              f"{t.pages_in_use - expect} pages")
-    if eng._swapped:
+    if sched.busy:
+        _fail("check_drained on a scheduler with live work")
+    t = sched.table
+    if t is not None:
+        expect = len(t._prefix) + len(t._seized)
+        if t.pages_in_use != expect:
+            _fail(f"post-drain pages_in_use={t.pages_in_use}, expected "
+                  f"{expect} (prefix {len(t._prefix)} + seized "
+                  f"{len(t._seized)}) — leaked "
+                  f"{t.pages_in_use - expect} pages")
+    if sched.swapped:
         _fail(f"post-drain swap state survives for uids "
-              f"{sorted(eng._swapped)}")
-    if eng._prefilling:
+              f"{sorted(sched.swapped)}")
+    if sched.prefilling:
         _fail(f"post-drain prefill state survives for slots "
-              f"{sorted(eng._prefilling)}")
-    check_step(eng)
+              f"{sorted(sched.prefilling)}")
+    check_step(sched)
 
 
 def expected_decodes(req, prefix_tokens: int, max_seq: int) -> int:

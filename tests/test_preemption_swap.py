@@ -186,7 +186,7 @@ class TestEnginePreemption:
         eng, reqs = _overload(0, policy)
         assert eng.stats.preemptions > 0
         assert eng.stats.swapped_pages > 0
-        invariants.check_drained(eng)
+        invariants.check_drained(eng.sched)
         tally = invariants.check_trace_conservation(
             eng.trace, reqs, max_seq=eng.max_seq)
         # every swap_out round-tripped, page counts matched
@@ -271,7 +271,7 @@ class TestEnginePreemption:
         ("lifo", "69fffc60d8428c2f"), ("longest", "dbfe9b6a4a0c2447")])
     def test_plan_only_trace_and_lengths_are_pinned(self, policy, digest):
         """A seeded overload run with preemption gives the records and
-        the per-step host lengths (``_lens``) pinned here, so changes to
+        the per-step host lengths (``sched.lens``) pinned here, so changes to
         the jitted path's bookkeeping leave the plan-only path as it
         was."""
         @dataclasses.dataclass
@@ -279,7 +279,7 @@ class TestEnginePreemption:
             seen: list
 
             def on_step(self, eng, step):
-                self.seen.append(eng._lens.tolist())
+                self.seen.append(eng.sched.lens.tolist())
 
         eng = _engine(slots=3, max_seq=64, kv_pool_pages=13)
         reqs = faults.adversarial_requests(60, seed=0, max_seq=64)
@@ -293,8 +293,35 @@ class TestEnginePreemption:
         assert eng.stats.drained and eng.stats.preemptions > 0
         recs = [(r.kind, r.step_idx, r.slots, r.uids, r.arrival_event,
                  r.n_tokens) for r in eng.trace]
-        assert hashlib.sha256(repr((recs, log.seen, eng._lens.tolist()))
+        assert hashlib.sha256(repr((recs, log.seen, eng.sched.lens.tolist()))
                               .encode()).hexdigest()[:16] == digest
+
+    def test_decode_growth_failure_swaps_out_and_pages_are_pinned(self):
+        """A pool seizure while slots decode makes a slot's page growth
+        fail in a decode step; under lifo it is swapped out, not a
+        crash.  A plan step grows and retires slot by slot, so a page a
+        retiring slot frees is there for the next slot's growth.  The
+        records, the pages their plans stream and the seconds one
+        replay prices for each are pinned here."""
+        eng = _engine(slots=3, max_seq=64, kv_pool_pages=16)
+        reqs = faults.adversarial_requests(12, seed=6, max_seq=64)
+        eng.run_open_loop(reqs, faults.storm_arrivals(12, 400.0, seed=6),
+                          prefill_chunk_tokens=8, est_step_s=1e-4,
+                          est_prefill_s_per_token=1e-5, preempt="lifo",
+                          debug_invariants=True,
+                          faults=faults.PoolShrinkFault(20, 16, 50))
+        assert eng.stats.drained and eng.stats.preemptions > 0
+        invariants.check_trace_conservation(eng.trace, reqs,
+                                            max_seq=eng.max_seq)
+        _, per = replay_trace(system_for(Scenario(model="serve",
+                                                  mode="DC")),
+                              [r.plan for r in eng.trace])
+        rows = [(r.kind, r.step_idx, r.slots, r.uids, r.arrival_event,
+                 r.n_tokens, r.plan.name,
+                 [getattr(e, "page", None) for e in r.plan.events],
+                 repr(float(t))) for r, t in zip(eng.trace, per)]
+        assert (len(rows), hashlib.sha256(repr(rows).encode())
+                .hexdigest()[:16]) == (76, "95ea418e97a4ab1d")
 
     def test_policy_validation(self):
         eng = _engine()
@@ -350,7 +377,7 @@ class TestFaultInjection:
         inside the run), trace conservation — per seed and policy."""
         eng, reqs = _overload(seed, policy, n_requests=48)
         assert eng.n_finished == len(reqs)
-        invariants.check_drained(eng)
+        invariants.check_drained(eng.sched)
         invariants.check_trace_conservation(eng.trace, reqs,
                                             max_seq=eng.max_seq)
 
@@ -378,11 +405,11 @@ class TestFaultInjection:
         f = faults.PoolShrinkFault(at_step=0, n_pages=4,
                                    restore_step=2)
         f.on_step(eng, 0)
-        assert f.seized == 4 and eng._table.pages_in_use == 4
+        assert f.seized == 4 and eng.sched.table.pages_in_use == 4
         f.on_step(eng, 1)
-        assert eng._table.pages_in_use == 4
+        assert eng.sched.table.pages_in_use == 4
         f.on_step(eng, 2)
-        assert f.restored and eng._table.pages_in_use == 0
+        assert f.restored and eng.sched.table.pages_in_use == 0
 
     def test_smoke_main_exits_clean(self):
         assert faults.main(["--seeds", "0", "--requests", "24"]) == 0

@@ -5,6 +5,7 @@ import pytest
 
 from repro.configs import get_reduced
 from repro.models.model import Model
+from repro.serving import invariants
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.kv_cache import PagedCacheConfig, PagedKVCache
 
@@ -83,13 +84,26 @@ def _mirror_requests():
 def _serve_reading_device_lengths(model, params, decode, prefill, eos):
     """The engine's schedule written out plainly, reading each slot's
     cache length from the device: FIFO admission into free slots, one
-    batched decode step, then per slot the tests count, EOS, length.
-    Returns {uid: (tokens, retiring step, reason)}."""
+    batched decode step while any slot is held, and per slot, after its
+    first token and after each decode, the tests count, EOS, length.
+    Returns {uid: (tokens, step count when it retired, reason)}."""
     cache = model.init_cache(MIRROR_SLOTS, MIRROR_SEQ)
     queue, held = _mirror_requests(), [None] * MIRROR_SLOTS
     nxt = np.zeros(MIRROR_SLOTS, np.int32)
     out, left, step = {}, {}, 0
+
+    def retire_if_done(s, tok):
+        why = ("count" if left[s] <= 0 else
+               "eos" if tok == eos else
+               "length" if int(cache["len"][s]) >= MIRROR_SEQ - 1
+               else None)
+        if why:
+            done.append((held[s].uid, why))
+            held[s] = None
+            cache["len"] = cache["len"].at[s].set(0)
+
     while queue or any(r is not None for r in held):
+        done = []
         for s in range(MIRROR_SLOTS):
             if held[s] is None and queue:
                 r = held[s] = queue.pop(0)
@@ -102,23 +116,21 @@ def _serve_reading_device_lengths(model, params, decode, prefill, eos):
                 cache["len"] = cache["len"].at[s].set(one["len"][0])
                 nxt[s] = int(jnp.argmax(logits[0, :model.cfg.vocab_size]))
                 out[r.uid], left[s] = [int(nxt[s])], r.max_new_tokens - 1
-        cache, logits = decode(params, cache, jnp.asarray(nxt))
-        step += 1
-        toks = np.asarray(jnp.argmax(logits[:, :model.cfg.vocab_size], -1))
-        for s, r in enumerate(held):
-            if r is None:
-                continue
-            nxt[s] = toks[s]
-            out[r.uid].append(int(toks[s]))
-            left[s] -= 1
-            why = ("count" if left[s] <= 0 else
-                   "eos" if toks[s] == eos else
-                   "length" if int(cache["len"][s]) >= MIRROR_SEQ - 1
-                   else None)
-            if why:
-                out[r.uid] = (out[r.uid], step, why)
-                held[s] = None
-                cache["len"] = cache["len"].at[s].set(0)
+                retire_if_done(s, nxt[s])
+        if any(r is not None for r in held):
+            cache, logits = decode(params, cache, jnp.asarray(nxt))
+            step += 1
+            toks = np.asarray(
+                jnp.argmax(logits[:, :model.cfg.vocab_size], -1))
+            for s, r in enumerate(held):
+                if r is None:
+                    continue
+                nxt[s] = toks[s]
+                out[r.uid].append(int(toks[s]))
+                left[s] -= 1
+                retire_if_done(s, toks[s])
+        for uid, why in done:
+            out[uid] = (out[uid], step, why)
     return out
 
 
@@ -150,9 +162,64 @@ def test_host_lengths_match_the_device_and_retire_as_it_would(arch):
         eng.submit(r)
     got = {}
     while eng.step():
-        np.testing.assert_array_equal(eng._lens,
+        invariants.check_step(eng.sched)
+        np.testing.assert_array_equal(eng.sched.lens,
                                       np.asarray(eng.cache["len"]))
         got.update((r.uid, (r.output, eng.stats.decode_steps))
                    for r in reqs if r.done_s is not None
                    and r.uid not in got)
     assert got == {u: (toks, step) for u, (toks, step, _) in want.items()}
+
+
+# ------------------------------------------- one retirement rule
+@pytest.fixture(scope="module")
+def qwen():
+    cfg = get_reduced("qwen2_0_5b")
+    return cfg, Model(cfg, remat="none").init(jax.random.PRNGKey(0))
+
+
+def _first_token(qwen, prompt):
+    eng = ServingEngine(*qwen, slots=2, max_seq=MIRROR_SEQ)
+    req = Request(uid=0, prompt=prompt, max_new_tokens=2)
+    eng.submit(req)
+    eng.step()
+    return req.output[0]
+
+
+@pytest.mark.parametrize("why", ["count", "eos", "length"])
+def test_a_request_retires_at_its_first_token(qwen, why):
+    """A request whose count, EOS or length is reached by its first
+    token retires at admission with that one token and no decode step,
+    as the plan-only path records it."""
+    n = MIRROR_SEQ - 1 if why == "length" else 8
+    prompt = np.arange(1, n + 1, dtype=np.int32)
+    eos = _first_token(qwen, prompt) if why == "eos" else None
+    eng = ServingEngine(*qwen, slots=2, max_seq=MIRROR_SEQ,
+                        eos_token=eos)
+    req = Request(uid=0, prompt=prompt,
+                  max_new_tokens=1 if why == "count" else 5)
+    eng.submit(req)
+    assert eng.step()
+    assert len(req.output) == 1 and req.done_s is not None
+    assert eng.stats.decode_steps == 0 and eng.stats.tokens_out == 1
+    assert eng.slot_req == [None, None] and eng.sched.n_finished == 1
+    np.testing.assert_array_equal(eng.sched.lens, [0, 0])
+    np.testing.assert_array_equal(np.asarray(eng.cache["len"]), [0, 0])
+    assert not eng.step()
+
+
+def test_a_one_token_request_beside_others_gets_one_token(qwen):
+    eng = ServingEngine(*qwen, slots=2, max_seq=MIRROR_SEQ)
+    reqs = [Request(uid=i, prompt=np.arange(1, 7 + i, dtype=np.int32),
+                    max_new_tokens=new) for i, new in enumerate((1, 4, 1))]
+    for r in reqs:
+        eng.submit(r)
+    stats = eng.run_until_drained()
+    assert [len(r.output) for r in reqs] == [1, 4, 1]
+    assert stats.tokens_out == 6 and stats.drained
+
+
+def test_prefix_tokens_need_a_plan_only_engine():
+    with pytest.raises(ValueError, match="prefix_tokens needs plan_only"):
+        ServingEngine(get_reduced("qwen2_0_5b"), None, record_plans=True,
+                      prefix_tokens=8)

@@ -314,6 +314,34 @@ def test_simulated_ttft_tpot_fold_back_onto_requests(
                                                   rel=1e-9)
 
 
+@pytest.mark.parametrize("device", [False, True])
+def test_pages_a_retirement_frees_go_to_growth_only_in_a_plan_step(
+        reduced_engine_setup, device):
+    """Slot 0 retires in the step in which slot 1 crosses a page
+    boundary.  A plan step grows and retires slot by slot, so slot 1
+    takes the page slot 0 has just freed; a device step wrote every
+    slot's token before anything retires, so the table grows for the
+    whole batch first and slot 1 takes a page from the free list."""
+    from repro.serving.engine import Request, ServingEngine
+    cfg, params = reduced_engine_setup
+    reqs = [Request(uid=i, prompt=np.arange(1, 9, dtype=np.int32),
+                    max_new_tokens=n) for i, n in enumerate((2, 4))]
+    if device:
+        eng = ServingEngine(cfg, params, slots=2, max_seq=32,
+                            record_plans=True)
+        for r in reqs:
+            eng.submit(r)
+        eng.run_until_drained()
+    else:
+        eng = ServingEngine(cfg, plan_only=True, slots=2, max_seq=32)
+        eng.run_open_loop(reqs, np.zeros(2), prefill_chunk_tokens=8)
+    dec = [r for r in eng.trace if r.kind == "decode"]
+    assert [d.slots for d in dec[:2]] == [(0, 1), (1,)]
+    pages = {e.page[1] for e in dec[1].plan.events
+             if getattr(e, "page", None) and e.page[0] == "L0.k"}
+    assert sorted(pages) == ([1, 3] if device else [1, 2])
+
+
 def test_engine_defers_admission_when_pool_full_then_readmits(
         reduced_engine_setup):
     """full -> drain -> re-admit: a shadow pool holding only 2 prompts
@@ -328,8 +356,8 @@ def test_engine_defers_admission_when_pool_full_then_readmits(
     assert all(len(r.output) == r.max_new_tokens for r in reqs)
     assert not eng.queue
     # page reuse across re-admissions: never more than 2 pages live
-    assert eng._table.pages_in_use == 0
-    eng._table.validate()              # partitions intact post-drain
+    assert eng.sched.table.pages_in_use == 0
+    eng.sched.table.validate()              # partitions intact post-drain
     prefills = [r for r in eng.trace if r.kind == "prefill"]
     assert len(prefills) == len(reqs)
     free_eng, free_reqs = _run_recorded(cfg, params, slots=4,
@@ -358,8 +386,8 @@ def test_conservative_admission_survives_decode_growth(
     eng.run_until_drained(max_steps=500)     # must not RuntimeError
     assert all(len(r.output) == r.max_new_tokens for r in reqs)
     assert eng.deferred_admissions > 0
-    assert eng._table.pages_in_use == 0
-    eng._table.validate()
+    assert eng.sched.table.pages_in_use == 0
+    eng.sched.table.validate()
 
 
 def test_never_fitting_request_raises_instead_of_livelocking(
